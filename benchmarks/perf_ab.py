@@ -82,7 +82,7 @@ def lower_train(arch: str, *, ce_mode="onehot", microbatches=None, seq=4096, bat
         step, in_shardings=(named(mesh, st_specs), named(mesh, b_specs)),
         donate_argnums=(0,),
     )
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jitted.lower(st, b_s).compile()
     return report(compiled)
 

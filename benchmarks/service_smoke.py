@@ -1,7 +1,7 @@
 """Campaign-service smoke (CI fast tier).
 
 Boots the multi-tenant service on an ephemeral port, submits the 2-cell
-``benchmarks/specs/campaign_smoke.json`` from two concurrent clients
+``benchmarks/specs/service_smoke.json`` from two concurrent clients
 (different tenants), and asserts the ISSUE-7 acceptance properties:
 
 * every unique cell spec hash is decoded exactly once (the second tenant
@@ -27,7 +27,8 @@ import time
 from repro.core import Campaign, CampaignRunner, RunStore
 from repro.service import ServiceClient, make_server
 
-DEFAULT_SPEC = os.path.join(os.path.dirname(__file__), "specs", "campaign_smoke.json")
+# Host-only cells: pooled workers refuse device explorers (jax_nsga2).
+DEFAULT_SPEC = os.path.join(os.path.dirname(__file__), "specs", "service_smoke.json")
 TENANTS = ("alice", "bob")
 
 

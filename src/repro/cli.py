@@ -309,17 +309,17 @@ def _cmd_sim_info(args) -> int:
         AUTO_CPU_MAX_TASKS,
         AUTO_MIN_BATCH,
         SIM_BACKENDS,
-        _jax_platform,
     )
+    from .devices import platform
 
     print(f"simulation enabled: {sim.simulation_enabled()}")
     print(f"engine sim_backend values: {SIM_BACKENDS}")
     print(f"batched backends: {sim.BATCH_BACKENDS}")
-    print(f"jax platform: {_jax_platform()}")
+    print(f"jax platform: {platform()}")
     print(
         f"auto selection: events below batch {AUTO_MIN_BATCH}; on CPU, "
         f"pallas up to {AUTO_CPU_MAX_TASKS} tasks, vectorized beyond; "
-        f"pallas on TPU; vectorized elsewhere"
+        f"vectorized elsewhere (TPU included)"
     )
     return 0
 
@@ -527,8 +527,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     )
     p.add_argument("--spec",
                    default=os.path.join("benchmarks", "specs",
-                                        "campaign_smoke.json"),
-                   help="campaign spec to chaos-test (default: CI smoke)")
+                                        "service_smoke.json"),
+                   help="campaign spec to chaos-test (default: the host-only "
+                        "service smoke; pooled workers refuse jax_nsga2)")
     p.add_argument("--plans", type=int, default=20, help="fault plans to sweep")
     p.add_argument("--seed", type=int, default=0,
                    help="plan-generation seed (same seed, same plans)")

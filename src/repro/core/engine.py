@@ -47,6 +47,8 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from .. import faults, obs
+from ..devices import host_only_process
+from ..devices import platform as platform_of_jax
 from .decoders import get_decoder
 from .dse import (
     Genotype,
@@ -75,8 +77,8 @@ CACHE_MODES = ("canonical", "exact", "none")
 #                    patched, so an entire NSGA-II generation is a single
 #                    device call;
 #   "pallas"         deferred like "vectorized", through the Pallas
-#                    actor-step kernel (repro.kernels.sim_step; interpreter
-#                    mode off-TPU);
+#                    actor-step kernel (repro.kernels.sim_step; interpret
+#                    mode off-TPU, and not compilable for TPU yet);
 #   "auto"           deferred; each ξ-group picks events ↔ vectorized ↔
 #                    pallas from the JAX platform, the group's batch size,
 #                    and the structure size (resolve_sim_backend); choices
@@ -87,7 +89,7 @@ SIM_BACKENDS = (None, "auto", "events", "vectorized", "pallas")
 
 # "auto" thresholds.  Below AUTO_MIN_BATCH the compiled batched paths can't
 # amortize dispatch over the group, so the event-driven loop wins.  On CPU
-# the Pallas kernel runs in interpreter mode — fastest at population-sized
+# the Pallas kernel runs in interpret mode — fastest at population-sized
 # batches of small graphs (BENCH_sim.json), but its per-element round loop
 # scales with the task-table size, so structures past AUTO_CPU_MAX_TASKS
 # route to the fused-rounds lax backend instead.
@@ -95,13 +97,6 @@ AUTO_MIN_BATCH = 4
 AUTO_CPU_MAX_TASKS = 256
 
 
-def _jax_platform() -> str:
-    try:
-        import jax
-
-        return jax.default_backend()
-    except Exception:  # jax missing/misconfigured: events always works
-        return "none"
 
 
 def _task_count(graph) -> int:
@@ -122,21 +117,17 @@ def resolve_sim_backend(
 
     * tiny groups (< ``AUTO_MIN_BATCH``) → ``events``: per-phenotype loops
       beat compiled-batch dispatch;
-    * TPU → ``pallas``: the actor-step kernel keeps state on-chip;
     * CPU, small structures (≤ ``AUTO_CPU_MAX_TASKS`` tasks) → ``pallas``
-      (interpreter mode; fastest batch path at population sizes);
+      (interpret mode; fastest batch path at population sizes);
     * CPU, large structures → ``vectorized`` (fused one-hot rounds scale
       with dense task tables where the interpreted kernel can't);
-    * anything else (GPU, unknown, no JAX) → ``vectorized`` as the
-      portable lax path — or ``events`` when JAX is unavailable.
+    * anything else (TPU, GPU) → ``vectorized``.  On TPU the Pallas
+      kernel's round body does not compile (see
+      :mod:`repro.kernels.sim_step`), so the lax path owns the batches.
     """
-    plat = platform if platform is not None else _jax_platform()
-    if plat == "none":
-        return "events"
+    plat = platform if platform is not None else platform_of_jax()
     if batch_size < AUTO_MIN_BATCH:
         return "events"
-    if plat == "tpu":
-        return "pallas"
     if plat == "cpu":
         return "pallas" if n_tasks <= AUTO_CPU_MAX_TASKS else "vectorized"
     return "vectorized"
@@ -156,6 +147,8 @@ _SIM_PERIOD_DEFERRED = Objective(
 )
 
 _DEAD = -1  # sentinel for alleles the decoder never reads
+
+_log = obs.get_logger("engine")
 
 
 def _mc_dead_indices(space: GenotypeSpace) -> List[Tuple[int, List[int]]]:
@@ -208,6 +201,7 @@ def _init_worker(
     space, decoder, ilp_budget_s, pipelined, objective_names, defer_sim=False
 ) -> None:
     global _WORKER_ARGS
+    host_only_process()
     objectives = tuple(
         _SIM_PERIOD_DEFERRED if (defer_sim and name == "sim_period") else name
         for name in objective_names
@@ -319,10 +313,14 @@ class EvaluationEngine:
 
     def _ensure_pool(self):
         if self._pool is None:
+            import multiprocessing
             from concurrent.futures import ProcessPoolExecutor
 
+            # Host-only decode workers: spawned (a forked child would
+            # inherit the parent's device state) and pinned to the CPU.
             self._pool = ProcessPoolExecutor(
                 max_workers=self.n_workers,
+                mp_context=multiprocessing.get_context("spawn"),
                 initializer=_init_worker,
                 initargs=(
                     self.space,
@@ -422,6 +420,10 @@ class EvaluationEngine:
                             )
                         except Exception as e:  # noqa: BLE001 — degrade
                             self._sim_breaker_open.add(backend)
+                            _log.exception(
+                                "batched simulator %r failed; later ξ-groups "
+                                "use the events backend", backend,
+                            )
                             obs.event(
                                 "engine.sim_breaker_open", backend=backend,
                                 error=f"{type(e).__name__}: {e}",

@@ -205,7 +205,7 @@ def make_relaxed_eval(
 
     Pure JAX, jitted by the caller (the explorer wraps it together with
     ranking + variation into the generation step).  Requires
-    ``jax.experimental.enable_x64`` at trace time — capacity arithmetic is
+    ``jax.enable_x64(True)`` at trace time — capacity arithmetic is
     int64 and objective vectors float64.
     """
     unsupported = [o for o in objectives if o not in RELAXED_OBJECTIVES]
@@ -329,8 +329,10 @@ def make_relaxed_eval(
         window = dur.sum(1)
         core_load = jnp.zeros((t.P,), jnp.int64).at[core].add(window)
         occ = route_occ[core[:, None], q_slot]               # (A, Tmax, H)
-        link_load = jnp.einsum(
-            "at,ath->h", dur * (has_chan & valid), occ
+        # A masked product + sum, not a contraction: the TPU compiler has no
+        # 64-bit integer dot, and this stays exact in integers.
+        link_load = jnp.sum(
+            (dur * (has_chan & valid))[:, :, None] * occ, axis=(0, 1)
         )
         p_lb = jnp.maximum(
             jnp.int64(1), jnp.maximum(core_load.max(), link_load.max())

@@ -54,6 +54,7 @@ from ..core.explorers import (
 )
 from ..core.pareto import nondominated
 from ..core.problem import ExplorationProblem
+from ..devices import ensure_compile_cache
 from .decode import RELAXED_OBJECTIVES, DecodeTables, make_relaxed_eval
 from .encoding import PopulationLayout
 from .ranking import (
@@ -299,7 +300,6 @@ class JaxNSGA2Explorer:
     def _explore_relaxed(self, problem, engine, run, t0, on_generation) -> None:
         import jax
         import jax.random as jrandom
-        from jax.experimental import enable_x64
 
         objectives = tuple(problem.objectives)
         bad = [o for o in objectives if o not in RELAXED_OBJECTIVES]
@@ -348,7 +348,8 @@ class JaxNSGA2Explorer:
                     seen.add(p)
             return allg[keep], allF[keep]
 
-        with enable_x64():
+        ensure_compile_cache()
+        with jax.enable_x64(True):
             key = jrandom.PRNGKey(self.seed)
             key, k0 = jrandom.split(key)
             genes = np.asarray(

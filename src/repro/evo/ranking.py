@@ -10,25 +10,18 @@ Three device primitives over an objective matrix ``F`` of shape (N, k)
   single lexsort per objective groups each front into a contiguous
   segment, segment boundaries get ``inf``, interior points accumulate
   (next − prev) / (max − min) with the same ``inf``-safe rules as the
-  (fixed) host implementation.
+  host implementation.  The relaxed device-resident loop uses it, under
+  ``jax.enable_x64(True)``, with row order as the tie key.
 
-Bit-for-bit parity with :mod:`repro.core.pareto` is part of the contract,
-not an accident, and is what the property tests in ``tests/test_evo.py``
-pin: ranks are integers (trivially exact) and crowding runs in float64
-with the host's accumulation order — one add per objective, objectives in
-index order — so every IEEE operation matches the host's.  Because the
-host breaks value ties by *position in the front sequence* (Python's
-stable sort), :func:`crowding` takes an explicit ``tie_pos`` vector;
-:func:`parity_rank_crowd` reconstructs the host front sequence from the
-device domination matrix (same S-lists, same counters) and feeds its
-positions back in, which makes the exact-evaluation ``jax_nsga2`` path
-produce the same floats the host explorer computes.  The relaxed
-device-resident loop uses plain row order as the tie key instead — any
-fixed deterministic choice is valid there.
-
-Everything runs under ``jax.experimental.enable_x64`` — float32 cannot
-reproduce host float arithmetic — scoped to these calls so the float32 /
-int32 simulator jits elsewhere in the process are not retraced.
+The exact-evaluation path (:func:`parity_rank_crowd`) must reproduce the
+host explorer's ``rank_crowd`` bit for bit, and a TPU has no native
+float64.  So the device part there is integer only: objectives become
+order-preserving int32 keys (dense per-objective ranks, ``inf`` the
+largest), whose domination matrix equals the floats' exactly.  The host
+replays the front *sequence* from that matrix (same S-lists, same
+counters as :func:`fast_nondominated_sort`) and computes crowding with
+the reference's own :func:`repro.core.pareto.crowding_distance`, so every
+float is the host's by construction.
 """
 from __future__ import annotations
 
@@ -93,17 +86,15 @@ def nondomination_ranks(F):
     return rank
 
 
-def crowding(F, ranks, tie_pos=None):
+def crowding(F, ranks):
     """Crowding distance per row, all fronts at once, float64 (N,).
 
-    ``tie_pos`` breaks equal-value ties inside a front (smaller = earlier
-    in the front's sequence); defaults to row order.  Matches the host
-    :func:`repro.core.pareto.crowding_distance` bit-for-bit when given the
-    host's front-sequence positions: per objective, front boundaries are
-    *set* to ``inf`` (overwriting any accumulation), zero-span objectives
-    contribute nothing, infinite spans contribute ``inf`` exactly when one
-    neighbour is infinite and the other finite, and finite spans
-    accumulate (next − prev) / span in objective order.
+    Equal values inside a front are ordered by row.  The rules are the
+    host :func:`repro.core.pareto.crowding_distance`'s: per objective,
+    front boundaries are *set* to ``inf`` (overwriting any accumulation),
+    zero-span objectives contribute nothing, infinite spans contribute
+    ``inf`` exactly when one neighbour is infinite and the other finite,
+    and finite spans accumulate (next − prev) / span in objective order.
     """
     jax, jnp = _jnp()
     lax = jax.lax
@@ -112,11 +103,7 @@ def crowding(F, ranks, tie_pos=None):
     if n == 0:
         return jnp.zeros((0,), jnp.float64)
     ranks = jnp.asarray(ranks, jnp.int32)
-    pos = (
-        jnp.arange(n, dtype=jnp.int32)
-        if tie_pos is None
-        else jnp.asarray(tie_pos, jnp.int32)
-    )
+    pos = jnp.arange(n, dtype=jnp.int32)
     idx = jnp.arange(n)
     inf = jnp.float64(jnp.inf)
     d = jnp.zeros((n,), jnp.float64)
@@ -184,32 +171,44 @@ def host_front_sequence(dom: np.ndarray) -> List[List[int]]:
     return [f for f in fronts if f]
 
 
+def _order_keys(F: np.ndarray) -> np.ndarray:
+    """Dense per-objective ranks of ``F`` as int32: ``F[i,k] < F[j,k]`` ⇔
+    ``keys[i,k] < keys[j,k]`` (ties and ``inf`` included), so dominance
+    over the keys is dominance over the floats."""
+    return np.stack(
+        [np.unique(F[:, k], return_inverse=True)[1] for k in range(F.shape[1])],
+        axis=1,
+    ).astype(np.int32)
+
+
+_DOMINATION_JIT = None
+
+
 def parity_rank_crowd(
     objs: Sequence[Sequence[float]],
 ) -> Tuple[Dict[int, int], Dict[int, float]]:
     """Drop-in replacement for the host explorer's ``rank_crowd``:
-    domination + crowding on device, front sequence replayed host-side —
-    returns the same ``(rank, crowd)`` dicts bit-for-bit."""
-    import jax
-    from jax.experimental import enable_x64
+    domination on device over int32 order keys, front sequence and
+    crowding on the host — the same ``(rank, crowd)`` dicts bit-for-bit."""
+    global _DOMINATION_JIT
+    from ..core.pareto import crowding_distance
 
     n = len(objs)
     if n == 0:
         return {}, {}
-    with enable_x64():
-        F = np.asarray(objs, np.float64)
-        dom = np.asarray(domination_matrix(F))
-        fronts = host_front_sequence(dom)
-        ranks = np.zeros(n, np.int32)
-        tie_pos = np.zeros(n, np.int32)
-        for fi, front in enumerate(fronts):
-            for p, i in enumerate(front):
-                ranks[i] = fi
-        seq = [i for f in fronts for i in f]
-        for p, i in enumerate(seq):
-            tie_pos[i] = p
-        crowd = np.asarray(crowding(F, ranks, tie_pos))
-    return (
-        {i: int(ranks[i]) for i in range(n)},
-        {i: float(crowd[i]) for i in range(n)},
-    )
+    if _DOMINATION_JIT is None:
+        import jax
+
+        from ..devices import ensure_compile_cache
+
+        ensure_compile_cache()
+        _DOMINATION_JIT = jax.jit(domination_matrix)
+    dom = np.asarray(_DOMINATION_JIT(_order_keys(np.asarray(objs, np.float64))))
+    rank: Dict[int, int] = {}
+    crowd: Dict[int, float] = {}
+    for fi, front in enumerate(host_front_sequence(dom)):
+        d = crowding_distance(objs, front)
+        for i in front:
+            rank[i] = fi
+            crowd[i] = d[i]
+    return rank, crowd

@@ -19,11 +19,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..devices import ensure_compile_cache, on_tpu
 
 __all__ = ["mrb_decode_attention"]
 
@@ -31,8 +34,9 @@ __all__ = ["mrb_decode_attention"]
 def _kernel(
     t_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     *, block: int, capacity: int, window: int, softcap: float, n_blocks: int,
+    kv: int, d: int,
 ):
-    blk = pl.program_id(2)
+    blk = pl.program_id(1)
 
     @pl.when(blk == 0)
     def _init():
@@ -40,58 +44,47 @@ def _kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)         # [G, d]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)   # [BLK, d]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)   # [BLK, d]
-    d = q.shape[-1]
-
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) / math.sqrt(d)                            # [G, BLK]
-    if softcap > 0:
-        s = softcap * jnp.tanh(s / softcap)
-
     t = t_ref[0]
-    slot = blk * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)[0]
+    slot = blk * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
     slot_pos = t - jnp.mod(t - slot, capacity)  # floored mod (rem truncates)
-    valid = slot_pos >= 0
+    valid = slot_pos >= 0                       # [1, BLK]
     if window > 0:
         valid &= slot_pos > t - window
-    s = jnp.where(valid[None, :], s, -1e30)
 
-    m_prev = m_ref[...]                         # [G]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])             # [G, BLK]
-    l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_new
+    # One (BLK × kv·d) tile holds every ring; each ring's G readers consume
+    # its lane-aligned d-wide slice straight from VMEM.
+    for h in range(kv):
+        q = q_ref[0, h].astype(jnp.float32)                  # [G, d]
+        k = k_ref[0, :, h * d:(h + 1) * d].astype(jnp.float32)  # [BLK, d]
+        v = v_ref[0, :, h * d:(h + 1) * d].astype(jnp.float32)  # [BLK, d]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) / math.sqrt(d)                                     # [G, BLK]
+        if softcap > 0:
+            s = softcap * jnp.tanh(s / softcap)
+        s = jnp.where(valid, s, -1e30)
+
+        m_prev = m_ref[h]                                    # [G, 1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)                               # [G, BLK]
+        l_ref[h] = l_ref[h] * alpha + p.sum(axis=-1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        m_ref[h] = m_new
 
     @pl.when(blk == n_blocks - 1)
     def _finalize():
-        o_ref[0, 0] = (
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
+        o_ref[0] = (
+            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
         ).astype(o_ref.dtype)
 
 
 @functools.partial(
     jax.jit, static_argnames=("block", "window", "softcap", "interpret")
 )
-def mrb_decode_attention(
-    q: jnp.ndarray,
-    buf_k: jnp.ndarray,
-    buf_v: jnp.ndarray,
-    t: jnp.ndarray,
-    *,
-    window: int = 0,
-    softcap: float = 0.0,
-    block: int = 256,
-    interpret: bool = True,
-) -> jnp.ndarray:
-    """q: [B, H, d]; buf_k/v: [B, C, kv, d]; t: scalar position.
-    Returns [B, H, d]."""
+def _decode_attention(q, buf_k, buf_v, t, *, window, softcap, block, interpret):
     B, C, kv, d = buf_k.shape
     H = q.shape[1]
     G = H // kv
@@ -100,6 +93,10 @@ def mrb_decode_attention(
     n_blocks = C // block
     qr = q.reshape(B, kv, G, d)
     t_arr = jnp.asarray(t, jnp.int32).reshape(1)
+    # [B, C, kv, d] → [B, C, kv·d] is a free row-major view; a tile's last
+    # two dims are then (BLK, kv·d), which the TPU tiling accepts.
+    kr = buf_k.reshape(B, C, kv * d)
+    vr = buf_v.reshape(B, C, kv * d)
 
     out = pl.pallas_call(
         functools.partial(
@@ -109,23 +106,47 @@ def mrb_decode_attention(
             window=window,
             softcap=softcap,
             n_blocks=n_blocks,
+            kv=kv,
+            d=d,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B, kv, n_blocks),
+            grid=(B, n_blocks),
             in_specs=[
-                pl.BlockSpec((1, 1, G, d), lambda b, h, c, tt: (b, h, 0, 0)),
-                pl.BlockSpec((1, block, 1, d), lambda b, h, c, tt: (b, c, h, 0)),
-                pl.BlockSpec((1, block, 1, d), lambda b, h, c, tt: (b, c, h, 0)),
+                pl.BlockSpec((1, kv, G, d), lambda b, c, tt: (b, 0, 0, 0)),
+                pl.BlockSpec((1, block, kv * d), lambda b, c, tt: (b, c, 0)),
+                pl.BlockSpec((1, block, kv * d), lambda b, c, tt: (b, c, 0)),
             ],
-            out_specs=pl.BlockSpec((1, 1, G, d), lambda b, h, c, tt: (b, h, 0, 0)),
+            out_specs=pl.BlockSpec((1, kv, G, d), lambda b, c, tt: (b, 0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G, d), jnp.float32),
+                pltpu.VMEM((kv, G, 1), jnp.float32),
+                pltpu.VMEM((kv, G, 1), jnp.float32),
+                pltpu.VMEM((kv, G, d), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, kv, G, d), q.dtype),
         interpret=interpret,
-    )(t_arr, qr, buf_k, buf_v)
+    )(t_arr, qr, kr, vr)
     return out.reshape(B, H, d)
+
+
+def mrb_decode_attention(
+    q: jnp.ndarray,
+    buf_k: jnp.ndarray,
+    buf_v: jnp.ndarray,
+    t: jnp.ndarray,
+    *,
+    window: int = 0,
+    softcap: float = 0.0,
+    block: int = 256,
+    interpret: Optional[bool] = None,
+) -> jnp.ndarray:
+    """q: [B, H, d]; buf_k/v: [B, C, kv, d]; t: scalar position.
+    Returns [B, H, d].  ``interpret`` defaults to ``not on_tpu()``."""
+    if interpret is None:
+        interpret = not on_tpu()
+    ensure_compile_cache()
+    return _decode_attention(
+        q, buf_k, buf_v, t, window=window, softcap=softcap, block=block,
+        interpret=interpret,
+    )

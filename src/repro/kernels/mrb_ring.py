@@ -13,11 +13,14 @@ recommended), H·BLK rows map to sublanes.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..devices import ensure_compile_cache, on_tpu
 
 __all__ = ["mrb_append", "DEFAULT_BLOCK"]
 
@@ -31,19 +34,27 @@ def _append_kernel(omega_ref, buf_ref, tok_ref, out_ref, *, block: int):
     out_ref[0, pl.dslice(row, 1), :, :] = tok_ref[0, :, :, :]
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def mrb_append(
     buf: jnp.ndarray,
     omega: jnp.ndarray,
     token: jnp.ndarray,
     *,
     block: int = DEFAULT_BLOCK,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Write `token` at ring slot ω.  Returns the updated buffer.
 
     buf: [B, C, H, d]; omega: scalar int32; token: [B, 1, H, d].
+    ``interpret`` defaults to ``not on_tpu()``.
     """
+    if interpret is None:
+        interpret = not on_tpu()
+    ensure_compile_cache()
+    return _append(buf, omega, token, block=block, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def _append(buf, omega, token, *, block: int, interpret: bool):
     B, C, H, d = buf.shape
     block = min(block, C)
     assert C % block == 0, f"capacity {C} must divide block {block}"

@@ -1,27 +1,18 @@
 """Public kernel entry points with backend dispatch.
 
-On TPU the Pallas kernels compile natively; everywhere else (this CPU
-container, unit tests) they run in ``interpret=True`` mode or fall back to
-the jnp oracle.  ``use_pallas`` lets callers force a path; tests sweep
-both and assert equality.
+On TPU the Pallas kernels compile natively; everywhere else they run in
+interpret mode (``interpret = not on_tpu()``, the default of every Pallas
+entry point) or fall back to the jnp oracle.  ``use_pallas`` lets callers
+force a path; tests sweep both and assert equality.
 """
 from __future__ import annotations
 
-import jax
-import jax.numpy as jnp
-
+from ..devices import on_tpu
 from . import ref
 from .decode_attention import mrb_decode_attention
 from .mrb_ring import mrb_append
 
 __all__ = ["ring_append", "ring_decode_attention", "on_tpu"]
-
-
-def on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 def ring_append(buf, omega, token, *, use_pallas: bool = None, interpret: bool = None):
@@ -30,9 +21,7 @@ def ring_append(buf, omega, token, *, use_pallas: bool = None, interpret: bool =
         use_pallas = on_tpu()
     if not use_pallas:
         return ref.mrb_append_ref(buf, omega, token)
-    return mrb_append(
-        buf, omega, token, interpret=(not on_tpu()) if interpret is None else interpret
-    )
+    return mrb_append(buf, omega, token, interpret=interpret)
 
 
 def ring_decode_attention(
@@ -45,6 +34,5 @@ def ring_decode_attention(
     if not use_pallas:
         return ref.decode_attention_ref(q, buf_k, buf_v, t, window, softcap)
     return mrb_decode_attention(
-        q, buf_k, buf_v, t, window=window, softcap=softcap,
-        interpret=(not on_tpu()) if interpret is None else interpret,
+        q, buf_k, buf_v, t, window=window, softcap=softcap, interpret=interpret,
     )

@@ -19,8 +19,13 @@ both siblings by construction (the parity suite asserts it anyway).  The
 firing-count target ``K`` rides along as a scalar-prefetch operand, so
 horizon-doubling reruns reuse the compiled kernel.
 
-Off-TPU the kernel runs in interpreter mode (pure JAX semantics) — CPU CI
-exercises exactly the code path an accelerator would compile.
+Off-TPU the kernel runs in interpret mode (pure JAX semantics).  On a
+TPU the block layout passes the tiling rule (per-cell operands and the
+``(dead, horizon)`` pair are 3-D blocks whose last two dims are full),
+but Mosaic refuses the shared round body itself ("infer-vector-layout:
+unsupported shape cast": the body broadcasts 1-D per-actor vectors into
+2-D/3-D masks), so ``sim_backend="auto"`` routes TPU batches to the
+``vectorized`` backend instead.
 """
 from __future__ import annotations
 
@@ -33,7 +38,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .. import obs
-from .ops import on_tpu
+from ..devices import on_tpu
 
 __all__ = ["build_pallas_sim"]
 
@@ -67,14 +72,15 @@ def build_pallas_sim(
         # refs: one per structure table (shared across cells), then the
         # per-cell batched operands, then the three outputs.
         table_refs = refs[: len(tables)]
-        tb_ref, core_ref, gamma_ref, fire_ref, dead_ref, hor_ref = refs[len(tables):]
+        tb_ref, core_ref, gamma_ref, fire_ref, stat_ref = refs[len(tables):]
         fire, dead, horizon = simulate_one(
             tuple(r[...] for r in table_refs),
-            tb_ref[0], core_ref[0], gamma_ref[0], k_ref[0],
+            tb_ref[0], core_ref[0], gamma_ref[0, 0], k_ref[0],
         )
         fire_ref[0] = fire
-        dead_ref[0] = dead.astype(jnp.int32)
-        hor_ref[0] = horizon
+        stat_ref[0] = jnp.concatenate(
+            [dead.astype(jnp.int32).reshape(1, 1), horizon.reshape(1, 1)], axis=1
+        )
 
     def whole(tab):  # structure tables: same full block for every cell
         n = tab.ndim
@@ -86,7 +92,7 @@ def build_pallas_sim(
     @functools.partial(jax.jit, static_argnames=())
     def run(tb, core_oh, gamma, K):
         B = tb.shape[0]
-        fire, dead, horizon = pl.pallas_call(
+        fire, stat = pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
@@ -94,25 +100,23 @@ def build_pallas_sim(
                 in_specs=[whole(tab) for tab in tables] + [
                     pl.BlockSpec((1, A, Tmax, 1 + H), lambda b, k: (b, 0, 0, 0)),
                     pl.BlockSpec((1, A, P), cell),
-                    pl.BlockSpec((1, C), lambda b, k: (b, 0)),
+                    pl.BlockSpec((1, 1, C), cell),
                 ],
                 out_specs=[
                     pl.BlockSpec((1, A, K_MAX), cell),
-                    pl.BlockSpec((1,), lambda b, k: (b,)),
-                    pl.BlockSpec((1,), lambda b, k: (b,)),
+                    pl.BlockSpec((1, 1, 2), cell),
                 ],
             ),
             out_shape=[
                 jax.ShapeDtypeStruct((B, A, K_MAX), jnp.int32),
-                jax.ShapeDtypeStruct((B,), jnp.int32),
-                jax.ShapeDtypeStruct((B,), jnp.int32),
+                jax.ShapeDtypeStruct((B, 1, 2), jnp.int32),
             ],
             interpret=interpret,
         )(
             jnp.asarray(K, jnp.int32).reshape(1),
             *[jnp.asarray(tab) for tab in tables],
-            tb, core_oh, gamma,
+            tb, core_oh, gamma[:, None, :],
         )
-        return fire, dead.astype(bool), horizon
+        return fire, stat[:, 0, 0].astype(bool), stat[:, 0, 1]
 
     return run

@@ -195,7 +195,7 @@ def run_cell(
     else:
         jitted, args = _prefill_cell(spec, shape, mesh)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()

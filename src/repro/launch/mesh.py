@@ -13,6 +13,7 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_mesh", "HW"]
 
@@ -31,9 +32,11 @@ class HW:
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Arbitrary mesh (smoke tests use small shapes on 1 device)."""
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh (smoke tests use small shapes on 1 device).  Axes are
+    ``Auto``: the models place data with ``with_sharding_constraint`` and
+    let GSPMD propagate, which JAX's default Explicit axes refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

@@ -43,7 +43,7 @@ from .layers import (
     softcap,
 )
 from .moe import init_moe, moe_fwd
-from .sharding_utils import shard_heads
+from .sharding_utils import ambient_mesh, shard_heads
 from .ssm import init_ssm, init_ssm_state, ssm_decode, ssm_fwd
 
 __all__ = [
@@ -69,7 +69,7 @@ ATTN_UNROLL_Q = True
 
 def constrain_activation(x: jnp.ndarray) -> jnp.ndarray:
     """Pin activations to (batch over data, sequence over model) sharding
-    when an ambient mesh is present (lowering under ``with mesh:``).
+    when an ambient mesh is present (lowering under ``jax.set_mesh``).
 
     Without the batch constraint, GSPMD can lose the batch sharding through
     the embedding gather and carry fully replicated activations through the
@@ -80,33 +80,29 @@ def constrain_activation(x: jnp.ndarray) -> jnp.ndarray:
     collective bytes for the dominant activation-memory term (observed:
     Nemotron-340B saved residuals 232 GiB → 15 GiB/device).  No-op outside
     a mesh context; dims that don't divide their axis stay unsharded."""
-    try:
-        from jax.interpreters import pxla
-        from jax.sharding import NamedSharding, PartitionSpec
+    from jax.sharding import NamedSharding, PartitionSpec
 
-        mesh = pxla.thread_resources.env.physical_mesh
-        if mesh.empty or x.ndim < 2:
-            return x
-        dp = tuple(a for a in mesh.axis_names if a != "model")
-        if not dp:
-            return x
-        dsize = 1
-        for a in dp:
-            dsize *= mesh.shape[a]
-        baxis = (dp if len(dp) > 1 else dp[0]) if x.shape[0] % dsize == 0 and x.shape[0] >= dsize else None
-        saxis = None
-        if (
-            x.ndim >= 3
-            and "model" in mesh.axis_names
-            and x.shape[1] % mesh.shape["model"] == 0
-            and x.shape[1] >= mesh.shape["model"]
-            and x.shape[1] > 1
-        ):
-            saxis = "model"
-        spec = PartitionSpec(*([baxis, saxis] + [None] * (x.ndim - 2)))
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
-    except Exception:
+    mesh = ambient_mesh()
+    if mesh is None or x.ndim < 2:
         return x
+    dp = tuple(a for a in mesh.axis_names if a != "model")
+    if not dp:
+        return x
+    dsize = 1
+    for a in dp:
+        dsize *= mesh.shape[a]
+    baxis = (dp if len(dp) > 1 else dp[0]) if x.shape[0] % dsize == 0 and x.shape[0] >= dsize else None
+    saxis = None
+    if (
+        x.ndim >= 3
+        and "model" in mesh.axis_names
+        and x.shape[1] % mesh.shape["model"] == 0
+        and x.shape[1] >= mesh.shape["model"]
+        and x.shape[1] > 1
+    ):
+        saxis = "model"
+    spec = PartitionSpec(*([baxis, saxis] + [None] * (x.ndim - 2)))
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
 # ---------------------------------------------------------------------------

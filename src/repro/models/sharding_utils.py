@@ -21,13 +21,14 @@ __all__ = ["ambient_mesh", "constrain", "shard_heads", "shard_ffn", "shard_seq"]
 
 
 def ambient_mesh():
-    try:
-        from jax.interpreters import pxla
+    """The (abstract) mesh installed by ``jax.set_mesh``, or None — also
+    inside ``shard_map``, whose Manual axes take no sharding constraint."""
+    from jax.sharding import AxisType
 
-        mesh = pxla.thread_resources.env.physical_mesh
-        return None if mesh.empty else mesh
-    except Exception:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or any(t != AxisType.Auto for t in mesh.axis_types):
         return None
+    return mesh
 
 
 def _data_axes(mesh):

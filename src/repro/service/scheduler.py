@@ -49,10 +49,15 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 
 from .. import faults, obs
 from ..core.runstore import RunStore
+from ..devices import host_only_process
 
 __all__ = ["SchedulerConfig", "WorkUnit", "Scheduler", "run_groups_local"]
 
 _log = obs.get_logger("service.scheduler")
+
+# Explorers that run on the device and therefore only in the process that
+# holds it (never in a pooled worker).
+DEVICE_EXPLORERS = ("jax_nsga2",)
 
 # Test-only hook: sleep this many seconds inside the worker after a cell
 # is claimed and announced, before decoding — gives kill/retry tests a
@@ -240,6 +245,7 @@ def _worker_main(wid: int, owner: str, task_q, result_q, cell_root: Optional[str
     """Worker loop: announce readiness, execute assigned units, heartbeat
     (and refresh held claims) from a side thread so a long decode never
     looks dead."""
+    host_only_process()
     store = RunStore(cell_root)
     obs.set_process_name(f"worker-{wid}")
     held: set = set()
@@ -383,7 +389,9 @@ class Scheduler:
         self.cfg = config or SchedulerConfig()
         self.on_event = on_event
         self.tenant_quotas = dict(tenant_quotas or {})
-        self._ctx = multiprocessing.get_context()
+        # Workers are host-only and spawned: a forked child would inherit
+        # the parent's device state, and the chip belongs to one process.
+        self._ctx = multiprocessing.get_context("spawn")
         self._result_q = self._ctx.Queue() if self.workers else None
         self._lock = threading.RLock()
         self._done_cv = threading.Condition(self._lock)
@@ -449,6 +457,21 @@ class Scheduler:
         obs.flush()
 
     # ------------------------------------------------------------- submit
+    def check_cells(self, cells: Sequence[Any]) -> None:
+        """Refuse cells a pooled worker cannot run.  Workers are host-only
+        processes (the chip belongs to one process), so a device explorer
+        there would silently run on the CPU."""
+        if not self.workers:
+            return
+        for c in cells:
+            if c.explorer in DEVICE_EXPLORERS:
+                raise ValueError(
+                    f"cell {c.spec_hash()[:12]} uses the device explorer "
+                    f"{c.explorer!r}, and pooled workers are host-only: run "
+                    "it in the device-owning process (jobs=1, or a service "
+                    "with workers=0)"
+                )
+
     def submit(
         self,
         campaign_id: str,
@@ -460,6 +483,7 @@ class Scheduler:
     ) -> int:
         """Enqueue one unit per (non-empty) engine-sharing group of
         :class:`CampaignCell`\\ s.  Returns the number of units queued."""
+        self.check_cells([c for g in groups for c in g])
         units = []
         with self._lock:
             for group in groups:

@@ -113,6 +113,7 @@ class CampaignService:
                                      retry_after_s=1.0)
         campaign = Campaign.from_json(campaign_spec)
         cells = campaign.expand()
+        self.scheduler.check_cells(cells)
         submission_id = f"{tenant}--{campaign.campaign_id()}"
         view = self.store.view(submission_id)
         view.write_manifest(campaign.manifest())

@@ -14,7 +14,8 @@ semantics (:mod:`repro.sim.model`):
   backends sharing one fused actor-parallel round program: the
   ``vmap``-batched lax implementation (``backend="vectorized"``) and the
   Pallas actor-step kernel (``backend="pallas"``,
-  :mod:`repro.kernels.sim_step`, interpreter mode off-TPU) — wired into
+  :mod:`repro.kernels.sim_step`, interpret mode on the CPU; it does not
+  compile for TPU yet) — wired into
   ``EvaluationEngine.evaluate_batch`` via ``sim_backend=``.
 
 The ``sim_period`` objective (registered in :mod:`repro.core.problem`)

@@ -19,11 +19,11 @@ arrays.  The hot path is throughput-shaped (ISSUE 4 rebuilt it):
   is sized to the power-of-two bucket of the requested firings and batch
   sizes are bucketed to powers of two, so horizon-doubling reruns and
   sub-batch retries compile at most once per bucket;
-* compiled functions are cached per structure in ``_COMPILED``;
-  ``REPRO_SIM_CACHE_DIR`` additionally persists XLA compilations on disk
-  (fresh processes pay retrace-only cold starts) and
-  ``REPRO_SIM_FAST_CPU`` configures XLA:CPU for this dispatch-bound
-  loop shape (see :func:`_wire_fast_cpu`).
+* compiled functions are cached per structure in ``_COMPILED`` and on
+  disk in the repo's one persistent compile cache
+  (:func:`repro.devices.ensure_compile_cache`), and ``REPRO_SIM_FAST_CPU``
+  configures XLA:CPU for this dispatch-bound loop shape when the process
+  is explicitly on the CPU (see :func:`_wire_fast_cpu`).
 
 The batch must share one (graph, architecture) pair — the task *structure*
 (actor order, task kinds, channels, reader slots) is graph-derived and
@@ -46,6 +46,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from .. import obs
+from ..devices import ensure_compile_cache
 from ..core.architecture import ArchitectureGraph
 from ..core.graph import ApplicationGraph
 from ..core.schedule import Schedule
@@ -110,8 +111,10 @@ def _wire_fast_cpu() -> None:
       pool gets one thread and kernels never migrate cores mid-loop
       (~2x); the affinity is restored immediately after init.
 
-    Disable with ``REPRO_SIM_FAST_CPU=0`` (automatically skipped on
-    accelerator platforms).
+    Acts only when the process is explicitly on the CPU
+    (``jax_platforms == "cpu"``): with the platform unset, initializing the
+    backend here could take an accelerator under a one-CPU affinity mask.
+    Disable with ``REPRO_SIM_FAST_CPU=0``.
     """
     global _FAST_CPU_WIRED
     if _FAST_CPU_WIRED:
@@ -120,16 +123,10 @@ def _wire_fast_cpu() -> None:
     if os.environ.get("REPRO_SIM_FAST_CPU", "1") in ("0", ""):
         return
     import jax
+    from jax._src import xla_bridge
 
-    try:  # private API — treat any change as "can't tell, don't touch"
-        from jax._src import xla_bridge
-
-        if xla_bridge.backends_are_initialized():
-            return  # too late to influence flags or pool size
-        if jax.config.jax_platforms not in (None, "", "cpu"):
-            return
-    except Exception:
-        return
+    if jax.config.jax_platforms != "cpu" or xla_bridge.backends_are_initialized():
+        return  # not explicitly CPU, or too late to influence flags/pool
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_cpu_use_thunk_runtime" not in flags:
         os.environ["XLA_FLAGS"] = (
@@ -145,35 +142,6 @@ def _wire_fast_cpu() -> None:
         jax.devices()  # backend init sizes its thread pool now
     finally:
         os.sched_setaffinity(0, full)
-
-
-_CACHE_WIRED = False
-
-
-def _wire_persistent_cache() -> None:
-    """Point JAX's persistent compilation cache at ``REPRO_SIM_CACHE_DIR``
-    (default ``~/.cache/repro-sim-jax``; set it empty or to ``0`` to
-    disable) so a fresh process pays retrace-only cold starts — the XLA
-    compile step itself is served from disk."""
-    global _CACHE_WIRED
-    if _CACHE_WIRED:
-        return
-    _CACHE_WIRED = True
-    cache_dir = os.environ.get(
-        "REPRO_SIM_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache", "repro-sim-jax"),
-    )
-    if not cache_dir or cache_dir == "0":
-        return
-    import jax
-
-    try:
-        if jax.config.jax_compilation_cache_dir is None:
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        pass  # older jax without the knobs: in-memory caching still works
 
 
 # --------------------------------------------------------------- lowering
@@ -601,7 +569,7 @@ def _get_compiled(
     if fn is None:
         with obs.span("sim.compile", backend=backend, k_max=int(k_max)):
             _wire_fast_cpu()
-            _wire_persistent_cache()
+            ensure_compile_cache()
             if backend == "pallas":
                 from ..kernels.sim_step import build_pallas_sim
 
@@ -677,7 +645,7 @@ def batch_simulate(
     sequence ``iterations, 2·iterations, …`` where its tail is periodic —
     so results are backend-identical.  ``backend`` selects the fused-scan
     lax implementation (``"vectorized"``) or the Pallas actor-step kernel
-    (``"pallas"``, interpreter mode off-TPU); ``donate=True`` donates the
+    (``"pallas"``, interpret mode off-TPU); ``donate=True`` donates the
     batched operand buffers to the compiled call (lax backend only — the
     Pallas route ignores it).
     """

@@ -26,7 +26,7 @@ small = dataclasses.replace(
 mesh = make_mesh((2, 4), ("data", "model"))
 shape = Shape("train_tiny", 64, 8, "train")
 jitted, args = dryrun._train_cell(small, shape, mesh)
-with mesh:
+with jax.set_mesh(mesh):
     compiled = jitted.lower(*args).compile()
 mem = compiled.memory_analysis()
 from repro.launch.hlo import analyze_hlo
@@ -41,7 +41,7 @@ print(json.dumps({
 # decode cell too
 shape_d = Shape("decode_tiny", 64, 8, "decode")
 jitted, args = dryrun._decode_cell(small, shape_d, mesh)
-with mesh:
+with jax.set_mesh(mesh):
     compiled = jitted.lower(*args).compile()
 print(json.dumps({"decode_ok": True}))
 """

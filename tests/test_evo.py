@@ -133,10 +133,13 @@ def test_exact_parity_generated_scenario_ilp():
 
 
 # ---------------------------------------------------- relaxed decode gate
-def test_relaxed_front_within_relhv_tolerance(sobel_arch):
+# Several seeds at 8 generations: the gate holds per seed, not for one
+# lucky PRNG stream (JAX's default stream changed under this test once).
+@pytest.mark.parametrize("seed", range(8, 16))
+def test_relaxed_front_within_relhv_tolerance(sobel_arch, seed):
     g, arch = sobel_arch
     problem = ExplorationProblem(graph=g, arch=arch, strategy="Reference")
-    cfg = dict(population=32, offspring=16, generations=4, seed=11)
+    cfg = dict(population=32, offspring=16, generations=8, seed=seed)
     host = get_explorer("nsga2", **cfg).explore(problem)
     dev = get_explorer("jax_nsga2", evaluation="relaxed", **cfg).explore(problem)
     assert dev.front, "relaxed exploration produced an empty front"

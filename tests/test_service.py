@@ -142,6 +142,19 @@ def test_worker_pool_decodes_each_hash_exactly_once(tmp_path):
         sched.close()
 
 
+def test_pooled_workers_refuse_device_explorer_cells(tmp_path):
+    """Pool workers are host-only processes (one process holds the chip):
+    a jax_nsga2 cell sent to a pool fails fast, naming jobs=1, instead of
+    silently running on the CPU; the device-owning process accepts it."""
+    camp = tiny_campaign(explorer="jax_nsga2", share_engines=False)
+    cells = camp.expand()
+    with pytest.raises(ValueError, match="jobs=1"):
+        CampaignRunner(camp, root=str(tmp_path), jobs=2).run()
+    with pytest.raises(ValueError, match="jobs=1"):
+        Scheduler(RunStore(None), workers=2).submit("c", "t", [[c] for c in cells])
+    assert Scheduler(RunStore(None), workers=0).submit("c", "t", [cells]) == 1
+
+
 # ============================================================== supervision
 def test_sigkilled_worker_unit_retried_to_completion(tmp_path, monkeypatch):
     """SIGKILL a worker mid-cell: the supervisor respawns it, releases
