@@ -14,8 +14,8 @@ A. relaxed ``jax_nsga2`` at population 512 / 256 offspring on the
    23 would bring ~512 patterns at this population).  The front
    must be non-empty and every front point must re-decode to a schedule
    ``verify_schedule`` passes; relHV against host ``nsga2`` at the same
-   budget, ``evo.retraces`` and the first generation (compile) are
-   printed.
+   budget, ``jax.compiles`` (programs JAX prepared during the run) and the
+   first generation (compile) are printed.
 B. exact ``jax_nsga2`` at the paper's population 100 / 25 offspring on
    Multicamera: front and history bit-identical to host ``nsga2``.
 C. 64 feasible Multicamera decodes (all ξ = 1): the events and batched
@@ -141,7 +141,7 @@ def _counter(name, **match):
 def phase_a(sizes, app, strategy):
     def run(smoke):
         obs.flush()
-        retraces0 = _counter("evo.retraces")
+        compiles0 = _counter("jax.compiles")
         problem = ExplorationProblem(
             graph=app(), arch=paper_architecture(),
             objectives=SIM_OBJECTIVES, strategy=strategy,
@@ -159,13 +159,13 @@ def phase_a(sizes, app, strategy):
         host = get_explorer("nsga2", **cfg).explore(problem, engine=host_engine)
         checked, violations = _verify_front(problem, dev)
         obs.flush()
-        retraces = _counter("evo.retraces") - retraces0
+        compiles = _counter("jax.compiles") - compiles0
         relhv = relative_hypervolume(dev.front, host.front) if dev.front else 0.0
         ok = bool(dev.front) and checked == len(dev.front) and violations == 0
         detail = dict(
             app=app.__name__, strategy=strategy, front=len(dev.front),
             host_front=len(host.front), verified=checked, violations=violations,
-            relhv=relhv, retraces=retraces, first_generation_compile_s=first_s,
+            relhv=relhv, compiles=compiles, first_generation_compile_s=first_s,
             steady_generation_s=steady, device_explore_wall_s=dev.wall_s,
             host_explore_wall_s=host.wall_s,
             sim_backend_choices=dict(dev_engine.sim_backend_choices),
@@ -173,7 +173,7 @@ def phase_a(sizes, app, strategy):
         line = (
             f"relaxed {strategy} on {app.__name__}: front={len(dev.front)} "
             f"verified={checked} violations={violations} relHV={relhv!r} "
-            f"evo.retraces={retraces} compile(first generation)_s={first_s!r} "
+            f"jax.compiles={compiles} compile(first generation)_s={first_s!r} "
             f"steady_generation_s={steady!r} device_wall_s={dev.wall_s!r} "
             f"host_nsga2_wall_s={host.wall_s!r}"
         )

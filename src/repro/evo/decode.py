@@ -204,7 +204,9 @@ def make_relaxed_eval(
     """Build the fused per-ξ-pattern evaluation: ``genes (N, G) → F (N, k)``.
 
     Pure JAX, jitted by the caller (the explorer wraps it together with
-    ranking + variation into the generation step).  Requires
+    ranking + variation into the generation step).  Its device ops fall in
+    two named scopes: ``decode`` (genes → objectives, the simulator's
+    operands) and ``simulate`` (the fused simulator and its period).  Requires
     ``jax.enable_x64(True)`` at trace time — capacity arithmetic is
     int64 and objective vectors float64.
     """
@@ -263,129 +265,132 @@ def make_relaxed_eval(
         simulate_one, sim_tables = build_simulate_one(st, mrb_ports, sim_iters)
 
     def eval_one(genes):
-        # ---- gene decode -------------------------------------------------
-        # Layout [xi | cd | ba]: slices are static (closure constants).
-        cd_genes = lax.dynamic_slice_in_dim(genes, n_xi, n_cd)
-        ba_genes = lax.dynamic_slice_in_dim(genes, n_xi + n_cd, n_ba)
-        j = jnp.remainder(ba_genes[ba_gene_of], n_allowed)
-        core = allowed[jnp.arange(A), j]                     # (A,) core idx
-        d = cd_genes[cd_gene_of]                             # (C,) decision
-        p_rel = jnp.where(d < 2, core[prod_a], core[cons0_a])
+        with jax.named_scope("decode"):
+            # ---- gene decode -------------------------------------------------
+            # Layout [xi | cd | ba]: slices are static (closure constants).
+            cd_genes = lax.dynamic_slice_in_dim(genes, n_xi, n_cd)
+            ba_genes = lax.dynamic_slice_in_dim(genes, n_xi + n_cd, n_ba)
+            j = jnp.remainder(ba_genes[ba_gene_of], n_allowed)
+            core = allowed[jnp.arange(A), j]                     # (A,) core idx
+            d = cd_genes[cd_gene_of]                             # (C,) decision
+            p_rel = jnp.where(d < 2, core[prod_a], core[cons0_a])
 
-        # ---- Algorithm 2: greedy binding with fallback chains ------------
-        need = gamma0 * phi
-        first_q = mem_sel[d, p_rel]
-        # PROD→TILE-PROD and CONS→TILE-CONS; TILE-* and GLOBAL fall back to
-        # global directly.
-        second_q = jnp.where(
-            (d == 0) | (d == 2), mem_sel[jnp.clip(d + 1, 0, 4), p_rel],
-            mem_sel[4, p_rel],
-        )
-        third_q = mem_sel[4, p_rel]
+            # ---- Algorithm 2: greedy binding with fallback chains ------------
+            need = gamma0 * phi
+            first_q = mem_sel[d, p_rel]
+            # PROD→TILE-PROD and CONS→TILE-CONS; TILE-* and GLOBAL fall back to
+            # global directly.
+            second_q = jnp.where(
+                (d == 0) | (d == 2), mem_sel[jnp.clip(d + 1, 0, 4), p_rel],
+                mem_sel[4, p_rel],
+            )
+            third_q = mem_sel[4, p_rel]
 
-        def bind_step(usage, ins):
-            nd, q1, q2, q3 = ins
-            ok1 = usage[q1] + nd <= mem_cap[q1]
-            ok2 = usage[q2] + nd <= mem_cap[q2]
-            q = jnp.where(ok1, q1, jnp.where(ok2, q2, q3))
-            return usage.at[q].add(nd), q
+            def bind_step(usage, ins):
+                nd, q1, q2, q3 = ins
+                ok1 = usage[q1] + nd <= mem_cap[q1]
+                ok2 = usage[q2] + nd <= mem_cap[q2]
+                q = jnp.where(ok1, q1, jnp.where(ok2, q2, q3))
+                return usage.at[q].add(nd), q
 
-        usage0 = jnp.zeros((mem_cap.shape[0],), jnp.int64)
-        _, q_of = lax.scan(bind_step, usage0, (need, first_q, second_q, third_q))
+            usage0 = jnp.zeros((mem_cap.shape[0],), jnp.int64)
+            _, q_of = lax.scan(bind_step, usage0, (need, first_q, second_q, third_q))
 
-        # ---- per-slot durations (Eq. 11 / τ(a, ϑ)) -----------------------
-        q_slot = q_of[cidx]                                  # (A, Tmax)
-        dur_comm = tau[cidx, core[:, None], q_slot]
-        e_a = exec_time[jnp.arange(A), core]
-        dur = jnp.where(
-            has_chan & valid,
-            dur_comm,
-            jnp.where(valid & ~has_chan, e_a[:, None], 0),
-        ).astype(jnp.int64)
+            # ---- per-slot durations (Eq. 11 / τ(a, ϑ)) -----------------------
+            q_slot = q_of[cidx]                                  # (A, Tmax)
+            dur_comm = tau[cidx, core[:, None], q_slot]
+            e_a = exec_time[jnp.arange(A), core]
+            dur = jnp.where(
+                has_chan & valid,
+                dur_comm,
+                jnp.where(valid & ~has_chan, e_a[:, None], 0),
+            ).astype(jnp.int64)
 
-        # ---- ASAP pass (uncontended list schedule) -----------------------
-        def asap(k, carry):
-            wfin, rfin, wstart = carry
-            ws = jnp.max(jnp.where(in0mask[k], wfin, 0))
-            ends = ws + jnp.cumsum(dur[k])
-            starts = ends - dur[k]
-            sc = slot_ch[k]                                  # (Tmax, C)
-            r_t = jnp.where(is_rd[k, :, None] & sc, ends[:, None], -big).max(0)
-            w_s = jnp.where(is_wr[k, :, None] & sc, starts[:, None], -big).max(0)
-            w_f = jnp.where(is_wr[k, :, None] & sc, ends[:, None], -big).max(0)
-            rfin = jnp.maximum(rfin, r_t)
-            wstart = jnp.where(outmask[k], w_s, wstart)
-            wfin = jnp.where(outmask[k], w_f, wfin)
-            return wfin, rfin, wstart
+            # ---- ASAP pass (uncontended list schedule) -----------------------
+            def asap(k, carry):
+                wfin, rfin, wstart = carry
+                ws = jnp.max(jnp.where(in0mask[k], wfin, 0))
+                ends = ws + jnp.cumsum(dur[k])
+                starts = ends - dur[k]
+                sc = slot_ch[k]                                  # (Tmax, C)
+                r_t = jnp.where(is_rd[k, :, None] & sc, ends[:, None], -big).max(0)
+                w_s = jnp.where(is_wr[k, :, None] & sc, starts[:, None], -big).max(0)
+                w_f = jnp.where(is_wr[k, :, None] & sc, ends[:, None], -big).max(0)
+                rfin = jnp.maximum(rfin, r_t)
+                wstart = jnp.where(outmask[k], w_s, wstart)
+                wfin = jnp.where(outmask[k], w_f, wfin)
+                return wfin, rfin, wstart
 
-        init = (
-            jnp.zeros((C,), jnp.int64),
-            jnp.full((C,), -big),
-            jnp.full((C,), -big),
-        )
-        _, rfin, wstart = lax.fori_loop(0, A, asap, init)
+            init = (
+                jnp.zeros((C,), jnp.int64),
+                jnp.full((C,), -big),
+                jnp.full((C,), -big),
+            )
+            _, rfin, wstart = lax.fori_loop(0, A, asap, init)
 
-        # ---- resource loads → period lower bound (Alg. 4, line 3) --------
-        window = dur.sum(1)
-        core_load = jnp.zeros((t.P,), jnp.int64).at[core].add(window)
-        occ = route_occ[core[:, None], q_slot]               # (A, Tmax, H)
-        # A masked product + sum, not a contraction: the TPU compiler has no
-        # 64-bit integer dot, and this stays exact in integers.
-        link_load = jnp.sum(
-            (dur * (has_chan & valid))[:, :, None] * occ, axis=(0, 1)
-        )
-        p_lb = jnp.maximum(
-            jnp.int64(1), jnp.maximum(core_load.max(), link_load.max())
-        )
+            # ---- resource loads → period lower bound (Alg. 4, line 3) --------
+            window = dur.sum(1)
+            core_load = jnp.zeros((t.P,), jnp.int64).at[core].add(window)
+            occ = route_occ[core[:, None], q_slot]               # (A, Tmax, H)
+            # A masked product + sum, not a contraction: the TPU compiler has no
+            # 64-bit integer dot, and this stays exact in integers.
+            link_load = jnp.sum(
+                (dur * (has_chan & valid))[:, :, None] * occ, axis=(0, 1)
+            )
+            p_lb = jnp.maximum(
+                jnp.int64(1), jnp.maximum(core_load.max(), link_load.max())
+            )
 
-        # ---- capacity enlargement estimate (Algorithms 3/4) --------------
-        seen = (rfin > -big) & (wstart > -big)
-        gamma_hat = jnp.where(
-            seen,
-            jnp.maximum(gamma0, delta + (rfin - wstart) // p_lb + 1),
-            gamma0,
-        )
-        gamma_hat = jnp.maximum(gamma_hat, 1)
+            # ---- capacity enlargement estimate (Algorithms 3/4) --------------
+            seen = (rfin > -big) & (wstart > -big)
+            gamma_hat = jnp.where(
+                seen,
+                jnp.maximum(gamma0, delta + (rfin - wstart) // p_lb + 1),
+                gamma0,
+            )
+            gamma_hat = jnp.maximum(gamma_hat, 1)
 
-        # ---- objectives --------------------------------------------------
-        vals: Dict[str, jnp.ndarray] = {}
-        vals["period"] = p_lb.astype(jnp.float64)
-        vals["memory"] = (gamma_hat * phi).sum().astype(jnp.float64)
-        used = jnp.zeros((t.P,), bool).at[core].set(True)
-        vals["core_cost"] = (used * kcost).sum()
-        wr_vol = prod_rate * phi * hops[core[prod_a], q_of]
-        rd_vol = (
-            read_rate
-            * phi[:, None]
-            * hops[core[reader_a], q_of[:, None]]
-            * reader_mask
-        ).sum(-1)
-        vals["comm_volume"] = (wr_vol + rd_vol).sum().astype(jnp.float64)
+            # ---- objectives --------------------------------------------------
+            vals: Dict[str, jnp.ndarray] = {}
+            vals["period"] = p_lb.astype(jnp.float64)
+            vals["memory"] = (gamma_hat * phi).sum().astype(jnp.float64)
+            used = jnp.zeros((t.P,), bool).at[core].set(True)
+            vals["core_cost"] = (used * kcost).sum()
+            wr_vol = prod_rate * phi * hops[core[prod_a], q_of]
+            rd_vol = (
+                read_rate
+                * phi[:, None]
+                * hops[core[reader_a], q_of[:, None]]
+                * reader_mask
+            ).sum(-1)
+            vals["comm_volume"] = (wr_vol + rd_vol).sum().astype(jnp.float64)
 
         if want_sim:
-            # The shared simulator body keeps int32 state even under the
-            # surrounding x64 scope (its integer reductions pin their
-            # dtype); only the period math below re-enters float64/int64.
-            tb = jnp.concatenate(
-                [
-                    dur[:, :, None],
-                    occ * (has_chan & valid)[:, :, None],
-                ],
-                axis=-1,
-            ).astype(jnp.int32)
-            # Compact per-element core remap (an element binds ≤ A cores).
-            eq = core[:, None] == core[None, :]
-            first = jnp.argmax(eq, axis=1)
-            is_first = first == jnp.arange(A)
-            compact = jnp.cumsum(is_first) - 1
-            core_oh = jax.nn.one_hot(compact[first], A, dtype=bool)
-            fire, dead, _ = simulate_one(
-                sim_tables, tb, core_oh, gamma_hat.astype(jnp.int32),
-                jnp.int32(sim_iters),
-            )
-            vals["sim_period"] = _device_period(jnp, fire, dead, sim_iters)
+            with jax.named_scope("simulate"):
+                # The shared simulator body keeps int32 state even under the
+                # surrounding x64 scope (its integer reductions pin their
+                # dtype); only the period math below re-enters float64/int64.
+                tb = jnp.concatenate(
+                    [
+                        dur[:, :, None],
+                        occ * (has_chan & valid)[:, :, None],
+                    ],
+                    axis=-1,
+                ).astype(jnp.int32)
+                # Compact per-element core remap (an element binds ≤ A cores).
+                eq = core[:, None] == core[None, :]
+                first = jnp.argmax(eq, axis=1)
+                is_first = first == jnp.arange(A)
+                compact = jnp.cumsum(is_first) - 1
+                core_oh = jax.nn.one_hot(compact[first], A, dtype=bool)
+                fire, dead, _ = simulate_one(
+                    sim_tables, tb, core_oh, gamma_hat.astype(jnp.int32),
+                    jnp.int32(sim_iters),
+                )
+                vals["sim_period"] = _device_period(jnp, fire, dead, sim_iters)
 
-        return jnp.stack([vals[o] for o in objectives])
+        with jax.named_scope("decode"):
+            return jnp.stack([vals[o] for o in objectives])
 
     return jax.vmap(eval_one)
 

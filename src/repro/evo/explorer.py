@@ -28,9 +28,20 @@ seam and two evaluation paths selected by the ``evaluation`` parameter:
 
 Recompile avoidance: populations are padded to power-of-two batch sizes
 and :class:`DecodeTables` are LRU-cached per ξ pattern, so steady-state
-generations reuse compiled steps; ``evo.compile`` / ``evo.execute`` spans
-and the ``evo.retraces`` counter make any residual retracing visible in
-the trace export.
+generations reuse compiled steps; JAX's own compile events, recorded by
+:mod:`repro.obs` as the ``jax.compiles`` counter, make any residual
+recompile visible in the trace export.
+
+Telemetry: each device call is an ``evo.execute`` span split into
+``evo.dispatch`` (the jitted call returns), ``evo.wait``
+(``block_until_ready``) and ``evo.fetch`` (device→host copy); everything
+after the last generation is ``evo.finalize``, with ``evo.final_decode``
+(the host engine's re-evaluation) and ``evo.hypervolume`` inside.  Every
+device step is cut into four named parts (``jax.named_scope``), so a
+profile attributes each device op to one of them: ``rank`` (ranking,
+crowding, truncation, the survivors' merge and gather), ``vary``
+(tournament, crossover, mutation, forced genes), ``decode`` (the relaxed
+decode) and ``simulate`` (the fused simulator).
 """
 from __future__ import annotations
 
@@ -66,10 +77,6 @@ from .ranking import (
 from .variation import init_population, mutate, tournament_pick, uniform_crossover
 
 __all__ = ["JaxNSGA2Explorer"]
-
-# Incremented inside every traced function body, so a delta across a call
-# means XLA retraced (same discipline as repro.sim.vectorized).
-_TRACE_COUNT = 0
 
 
 def _bucket(n: int) -> int:
@@ -148,17 +155,22 @@ class JaxNSGA2Explorer:
         try:
             if self.evaluation == "exact":
                 self._explore_exact(problem, engine, run, t0, on_generation)
+                final = None
             else:
-                self._explore_relaxed(problem, engine, run, t0, on_generation)
-            run.evaluations = engine.evaluations - ev0
-            run.cache_hits = engine.hits - hit0
-            run.cache_misses = engine.misses - miss0
-            _record_engine_meta(run, engine, choices0)
+                final = self._explore_relaxed(problem, engine, run, t0, on_generation)
+            with obs.span("evo.finalize", evaluation=self.evaluation):
+                if final is not None:
+                    self._final_decode(engine, run, *final)
+                run.evaluations = engine.evaluations - ev0
+                run.cache_hits = engine.hits - hit0
+                run.cache_misses = engine.misses - miss0
+                _record_engine_meta(run, engine, choices0)
+                if self.track_hypervolume:
+                    with obs.span("evo.hypervolume", generations=len(run.history)):
+                        _finalize_hypervolume(run)
         finally:
             if own_engine:
                 engine.close()
-        if self.track_hypervolume:
-            _finalize_hypervolume(run)
         run.wall_s = time.monotonic() - t0
         return run
 
@@ -250,54 +262,21 @@ class JaxNSGA2Explorer:
         fn = self._eval_cache.get(key)
         if fn is None:
             tab = self._tables(space, pattern, pipelined)
-            raw = make_relaxed_eval(tab, objectives, sim_iters=self.sim_iters)
-
-            def traced(genes):
-                global _TRACE_COUNT
-                _TRACE_COUNT += 1
-                return raw(genes)
-
-            fn = jax.jit(traced)
+            fn = jax.jit(make_relaxed_eval(tab, objectives, sim_iters=self.sim_iters))
             self._eval_cache[key] = fn
         return fn
 
     def _run_eval(self, fn, genes: np.ndarray, label: str) -> np.ndarray:
-        """Pad to the power-of-two bucket, execute, unpad — with the
-        compile/execute telemetry split: a call that traced is an
-        ``evo.compile`` span (and bumps ``evo.retraces`` when it was not
-        the first for this artifact), steady-state calls are
-        ``evo.execute``."""
-        global _TRACE_COUNT
-        import jax
-
+        """Pad to the power-of-two bucket, execute, unpad."""
         n = len(genes)
         pad = _bucket(max(1, n))
         if pad > n:
             genes = np.concatenate([genes, np.repeat(genes[:1], pad - n, 0)])
-        before = _TRACE_COUNT
-        span_name = self._span_name((id(fn), pad))
-        with obs.span(span_name, kind=label, n=n, pad=pad) as sp:
-            out = np.asarray(jax.block_until_ready(fn(genes)))
-            traced = _TRACE_COUNT - before
-            sp.set(retraced=traced > 0)
-        if traced:
-            obs.counter_add("evo.retraces", traced)
+        with obs.span("evo.execute", kind=label, n=n, pad=pad):
+            out = _execute(fn, (genes,))
         return out[:n]
 
-    def _span_name(self, key) -> str:
-        """First call of a jitted artifact at a given shape is the compile
-        span; later calls are steady-state execution.  A trace inside an
-        ``evo.execute`` span is a *retrace* (shape/dtype drift) and bumps
-        the ``evo.retraces`` counter."""
-        seen = getattr(self, "_compiled_keys", None)
-        if seen is None:
-            seen = self._compiled_keys = set()
-        if key in seen:
-            return "evo.execute"
-        seen.add(key)
-        return "evo.compile"
-
-    def _explore_relaxed(self, problem, engine, run, t0, on_generation) -> None:
+    def _explore_relaxed(self, problem, engine, run, t0, on_generation):
         import jax
         import jax.random as jrandom
 
@@ -395,19 +374,14 @@ class JaxNSGA2Explorer:
                 ) as sp:
                     key, kv = jrandom.split(key)
                     if fused is not None:
-                        out = self._run_eval_plain(fused, (kv, genes, F), "gen")
-                        genes, F = np.asarray(out[0]), np.asarray(out[1])
+                        genes, F = self._run_eval_plain(fused, (kv, genes, F), "gen")
                         relaxed_evals += self.offspring
                     else:
-                        children = np.asarray(
-                            self._run_eval_plain(vary_step, (kv, genes, F), "vary")
-                        )
+                        children = self._run_eval_plain(vary_step, (kv, genes, F), "vary")
                         cF = evaluate(children)
                         mg = np.concatenate([genes, children])
                         mF = np.concatenate([F, cF])
-                        sel = np.asarray(
-                            self._run_eval_plain(trunc_step, (mF,), "rank")
-                        )[: self.population]
+                        sel = self._run_eval_plain(trunc_step, (mF,), "rank")[: self.population]
                         genes, F = mg[sel], mF[sel]
                     arch_g, arch_F = fold_archive(arch_g, arch_F, genes, F)
                     run.history.append([tuple(v) for v in arch_F])
@@ -416,18 +390,17 @@ class JaxNSGA2Explorer:
                     run.wall_s = time.monotonic() - t0
                     on_generation(gen, run)
 
-        # True objectives for the survivors: the archive's relaxed vectors
-        # located promising genotypes; the host engine scores them.
-        cand = layout.decode(np.concatenate([arch_g, genes]))
-        uniq: List[Genotype] = []
-        seen = set()
-        for gt in cand:
-            if gt not in seen:
-                uniq.append(gt)
-                seen.add(gt)
-        final = engine.evaluate_batch(uniq)
-        _update_archive(run, final)
         run.meta["relaxed_evaluations"] = relaxed_evals
+        return layout, np.concatenate([arch_g, genes])
+
+    @staticmethod
+    def _final_decode(engine, run, layout: PopulationLayout, rows: np.ndarray) -> None:
+        """True objectives for the archive and the survivors: their relaxed
+        vectors located promising genotypes; the host engine scores them."""
+        with obs.span("evo.final_decode") as sp:
+            uniq: List[Genotype] = list(dict.fromkeys(layout.decode(rows)))
+            _update_archive(run, engine.evaluate_batch(uniq))
+            sp.set(n=len(uniq))
         run.meta["relaxed_final_candidates"] = len(uniq)
 
     def _fused_step(
@@ -445,37 +418,24 @@ class JaxNSGA2Explorer:
         compiles once and every later generation is a single dispatch."""
         import jax
         import jax.numpy as jnp
-        import jax.random as jrandom
 
         cache_key = ("fused", pattern, tuple(objectives))
         if cache_key in self._eval_cache:
             return self._eval_cache[cache_key]
         tab = self._tables(space, pattern, pipelined)
         raw_eval = make_relaxed_eval(tab, objectives, sim_iters=self.sim_iters)
-        bounds_d = jnp.asarray(bounds, jnp.int32)
-        mut_d = jnp.asarray(mut_mask)
-        forced_m = jnp.asarray(forced_mask)
-        forced_v = jnp.asarray(forced_vals, jnp.int32)
-        rate, count, mu = self.crossover_rate, self.offspring, self.population
+        vary = _vary_body(self.crossover_rate, self.offspring,
+                          bounds, mut_mask, forced_mask, forced_vals)
+        mu = self.population
 
         def step(key, genes, F):
-            global _TRACE_COUNT
-            _TRACE_COUNT += 1
-            ranks = nondomination_ranks(F)
-            crowd = crowding(F, ranks)
-            k1, k2, k3, k4 = jrandom.split(key, 4)
-            ia = tournament_pick(k1, ranks, crowd, count)
-            ib = tournament_pick(k2, ranks, crowd, count)
-            child = uniform_crossover(k3, genes[ia], genes[ib], rate)
-            child = mutate(k4, child, bounds_d, mut_d)
-            child = jnp.where(forced_m[None, :], forced_v[None, :], child)
+            child = vary(key, genes, F)
             cF = raw_eval(child)
-            mg = jnp.concatenate([genes, child])
-            mF = jnp.concatenate([F, cF])
-            ranks2 = nondomination_ranks(mF)
-            crowd2 = crowding(mF, ranks2)
-            sel = truncation_order(ranks2, crowd2)[:mu]
-            return mg[sel], mF[sel]
+            with jax.named_scope("rank"):
+                mg = jnp.concatenate([genes, child])
+                mF = jnp.concatenate([F, cF])
+                sel = _elitist_order(mF)[:mu]
+                return mg[sel], mF[sel]
 
         fn = jax.jit(step)
         self._eval_cache[cache_key] = fn
@@ -486,24 +446,58 @@ class JaxNSGA2Explorer:
         μ+λ truncation step (shared across ξ buckets — gene matrices have
         one shape regardless of pattern)."""
         import jax
-        import jax.numpy as jnp
-        import jax.random as jrandom
 
-        bounds_d = jnp.asarray(bounds, jnp.int32)
-        mut_d = jnp.asarray(mut_mask)
-        forced_m = jnp.asarray(forced_mask)
-        forced_v = jnp.asarray(forced_vals, jnp.int32)
-        rate = self.crossover_rate
-        count = self.offspring
         cache_key = ("vary", len(bounds))
         if cache_key in self._eval_cache:
             return self._eval_cache[cache_key]
 
-        def vary(key, genes, F):
-            global _TRACE_COUNT
-            _TRACE_COUNT += 1
+        def trunc(F):
+            with jax.named_scope("rank"):
+                return _elitist_order(F)
+
+        vary = _vary_body(self.crossover_rate, self.offspring,
+                          bounds, mut_mask, forced_mask, forced_vals)
+        out = (jax.jit(vary), jax.jit(trunc))
+        self._eval_cache[cache_key] = out
+        return out
+
+    def _run_eval_plain(self, fn, args, label: str):
+        """Execute a jitted step without padding (shapes are already static
+        per explorer configuration)."""
+        with obs.span("evo.execute", kind=label):
+            return _execute(fn, args)
+
+
+def _execute(fn, args):
+    """Call a jitted step and bring its result to the host, each phase a
+    span of its own: launching, waiting for the device, copying back."""
+    import jax
+
+    with obs.span("evo.dispatch"):
+        out = fn(*args)
+    with obs.span("evo.wait"):
+        out = jax.block_until_ready(out)
+    with obs.span("evo.fetch"):
+        return jax.tree.map(np.asarray, out)
+
+
+def _vary_body(rate, count, bounds, mut_mask, forced_mask, forced_vals):
+    """The traced parent ranking and variation shared by the fused step and
+    the separate vary jit: ``(key, genes (μ,G), F (μ,k)) → children (λ,G)``."""
+    import jax
+    import jax.numpy as jnp
+    import jax.random as jrandom
+
+    bounds_d = jnp.asarray(bounds, jnp.int32)
+    mut_d = jnp.asarray(mut_mask)
+    forced_m = jnp.asarray(forced_mask)
+    forced_v = jnp.asarray(forced_vals, jnp.int32)
+
+    def vary(key, genes, F):
+        with jax.named_scope("rank"):
             ranks = nondomination_ranks(F)
             crowd = crowding(F, ranks)
+        with jax.named_scope("vary"):
             k1, k2, k3, k4 = jrandom.split(key, 4)
             ia = tournament_pick(k1, ranks, crowd, count)
             ib = tournament_pick(k2, ranks, crowd, count)
@@ -511,29 +505,10 @@ class JaxNSGA2Explorer:
             child = mutate(k4, child, bounds_d, mut_d)
             return jnp.where(forced_m[None, :], forced_v[None, :], child)
 
-        def trunc(F):
-            global _TRACE_COUNT
-            _TRACE_COUNT += 1
-            ranks = nondomination_ranks(F)
-            crowd = crowding(F, ranks)
-            return truncation_order(ranks, crowd)
+    return vary
 
-        out = (jax.jit(vary), jax.jit(trunc))
-        self._eval_cache[cache_key] = out
-        return out
 
-    def _run_eval_plain(self, fn, args, label: str):
-        """Execute a jitted step with the compile/execute telemetry but no
-        padding (shapes are already static per explorer configuration)."""
-        global _TRACE_COUNT
-        import jax
-
-        before = _TRACE_COUNT
-        span_name = self._span_name((id(fn),))
-        with obs.span(span_name, kind=label) as sp:
-            out = jax.block_until_ready(fn(*args))
-            traced = _TRACE_COUNT - before
-            sp.set(retraced=traced > 0)
-        if traced:
-            obs.counter_add("evo.retraces", traced)
-        return out
+def _elitist_order(F):
+    """Rows of the merged population in elitist (rank, −crowding) order."""
+    ranks = nondomination_ranks(F)
+    return truncation_order(ranks, crowding(F, ranks))

@@ -184,6 +184,13 @@ def _order_keys(F: np.ndarray) -> np.ndarray:
 _DOMINATION_JIT = None
 
 
+def _ranked_domination(keys):
+    """:func:`domination_matrix` as the device part ``rank`` of a step."""
+    jax, _ = _jnp()
+    with jax.named_scope("rank"):
+        return domination_matrix(keys)
+
+
 def parity_rank_crowd(
     objs: Sequence[Sequence[float]],
 ) -> Tuple[Dict[int, int], Dict[int, float]]:
@@ -202,7 +209,7 @@ def parity_rank_crowd(
         from ..devices import ensure_compile_cache
 
         ensure_compile_cache()
-        _DOMINATION_JIT = jax.jit(domination_matrix)
+        _DOMINATION_JIT = jax.jit(_ranked_domination)
     dom = np.asarray(_DOMINATION_JIT(_order_keys(np.asarray(objs, np.float64))))
     rank: Dict[int, int] = {}
     crowd: Dict[int, float] = {}

@@ -21,6 +21,19 @@ immune to NTP steps); each sink's header line carries
 ``epoch_ns = time.time_ns() - perf_counter_ns()`` so the exporter can
 place every process's spans on one wall-clock timeline.
 
+Profiler bridge: while enabled, in a process that has imported JAX, every
+span is also a ``jax.profiler.TraceAnnotation`` of its name (a
+``StepTraceAnnotation`` numbered by ``gen`` for ``explorer.generation``),
+so a profile holds the spans on its host thread line, on the clock of the
+device ops.  JAX's own ``backend_compile_duration`` events (one per
+program prepared, compiled or loaded from the persistent cache) become a
+``jax.compiles`` counter.  The bridge also keys JAX's persistent compile
+cache on op metadata, so a program compiled under other
+``jax.named_scope`` names (another version of the program sharing the
+cache) is compiled anew rather than loaded with those names; the cost is
+a cold compile where only metadata differs, in enabled processes only.
+A process that never imported JAX never imports it here.
+
 Span names are dot-namespaced (``engine.decode``, ``service.cell``); the
 first component is the record's *category* (subsystem), which the trace
 tooling uses for grouping and the CI smoke uses to assert coverage.
@@ -55,6 +68,9 @@ OBS_DIR_ENV = "REPRO_OBS_DIR"
 DEFAULT_OBS_DIR = os.path.join("runs", "obs")
 
 _FLUSH_EVERY = 512  # records buffered before an automatic flush
+
+JAX_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+METADATA_IN_KEY = "jax_compilation_cache_include_metadata_in_key"
 
 
 def default_obs_dir() -> str:
@@ -144,11 +160,14 @@ def configure(on: Optional[bool] = None, obs_dir: Optional[str] = None) -> None:
     global _RECORDER, _CONFIGURED, _ON
     with _INIT_LOCK:
         flush()
+        _unbridge()
         _CONFIGURED = on
         _RECORDER = None
         _ON = None
         if obs_dir is not None:
             os.environ[OBS_DIR_ENV] = obs_dir
+    if enabled():
+        _get()  # bridge now, so compiles before the first span are seen
 
 
 def enabled() -> bool:
@@ -173,6 +192,7 @@ def _get() -> Optional[Recorder]:
         if rec is None or rec.pid != os.getpid():
             rec = Recorder(default_obs_dir())
             _RECORDER = rec
+    _bridge()
     return rec
 
 
@@ -187,10 +207,51 @@ def shutdown() -> None:
     reconfigure cleanly)."""
     global _RECORDER
     flush()
+    _unbridge()
     _RECORDER = None
 
 
 atexit.register(shutdown)
+
+
+# --------------------------------------------------------- profiler bridge
+_BRIDGE: Optional[tuple] = None  # (TraceAnnotation, StepTraceAnnotation, key setting before)
+_BRIDGE_LOCK = threading.Lock()
+
+
+def _bridge() -> Optional[tuple]:
+    """The profiler's annotation classes once JAX is imported (registering
+    the compile listener and keying the compile cache on op metadata on
+    first use), else None."""
+    global _BRIDGE
+    if _BRIDGE is None and "jax" in sys.modules:
+        with _BRIDGE_LOCK:
+            if _BRIDGE is None:
+                import jax
+                from jax import monitoring, profiler
+
+                before = getattr(jax.config, METADATA_IN_KEY)
+                jax.config.update(METADATA_IN_KEY, True)
+                monitoring.register_event_duration_secs_listener(_on_jax_duration)
+                _BRIDGE = (profiler.TraceAnnotation, profiler.StepTraceAnnotation, before)
+    return _BRIDGE
+
+
+def _unbridge() -> None:
+    global _BRIDGE
+    with _BRIDGE_LOCK:
+        if _BRIDGE is not None:
+            import jax
+            from jax import monitoring
+
+            monitoring.unregister_event_duration_listener(_on_jax_duration)
+            jax.config.update(METADATA_IN_KEY, _BRIDGE[2])
+            _BRIDGE = None
+
+
+def _on_jax_duration(event: str, secs: float, **kw: Any) -> None:
+    if event == JAX_COMPILE_EVENT:
+        counter_add("jax.compiles", 1)
 
 
 def set_process_name(name: str) -> None:
@@ -223,20 +284,30 @@ _NULL_SPAN = _NullSpan()
 
 
 class Span:
-    __slots__ = ("_rec", "name", "attrs", "_t0")
+    __slots__ = ("_rec", "name", "attrs", "_t0", "_ann")
 
     def __init__(self, rec: Recorder, name: str, attrs: Dict[str, Any]) -> None:
         self._rec = rec
         self.name = name
         self.attrs = attrs
         self._t0 = 0
+        self._ann = None
 
     def __enter__(self) -> "Span":
+        bridge = _BRIDGE or _bridge()
+        if bridge is not None:
+            if self.name == "explorer.generation" and "gen" in self.attrs:
+                self._ann = bridge[1](self.name, step_num=self.attrs["gen"])
+            else:
+                self._ann = bridge[0](self.name)
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type: Any, *exc: Any) -> bool:
         dur = time.perf_counter_ns() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
         self._rec.record(

@@ -567,15 +567,14 @@ def _get_compiled(
     full_key = (key, backend, donate)
     fn = _COMPILED.get(full_key)
     if fn is None:
-        with obs.span("sim.compile", backend=backend, k_max=int(k_max)):
-            _wire_fast_cpu()
-            ensure_compile_cache()
-            if backend == "pallas":
-                from ..kernels.sim_step import build_pallas_sim
+        _wire_fast_cpu()
+        ensure_compile_cache()
+        if backend == "pallas":
+            from ..kernels.sim_step import build_pallas_sim
 
-                fn = build_pallas_sim(static, cfg.mrb_ports, k_max)
-            else:
-                fn = _build_sim(static, cfg, k_max, donate)
+            fn = build_pallas_sim(static, cfg.mrb_ports, k_max)
+        else:
+            fn = _build_sim(static, cfg, k_max, donate)
         obs.counter_add("sim.cache_builds", backend=backend)
         _COMPILED[full_key] = fn
     return fn
@@ -611,16 +610,8 @@ def _run_batch(
     k_max = min(_bucket(max(2, total_iters)), cfg.max_iterations)
     key = (_structure_key(progs[0], cfg), Bb, k_max)
     fn = _get_compiled(static, key, cfg, k_max, backend, donate)
-    traces0 = _TRACE_COUNT
-    with obs.span(
-        "sim.execute", backend=backend, B=B, Bb=Bb, k_max=int(k_max)
-    ) as sp:
+    with obs.span("sim.execute", backend=backend, B=B, Bb=Bb, k_max=int(k_max)):
         fire, dead, horizon = fn(*arrs, np.int32(total_iters))
-        if _TRACE_COUNT != traces0:
-            # First call through a fresh compiled entry (or a shape-bucket
-            # retrace): this span's time is dominated by XLA compilation.
-            sp.set(retraced=True)
-            obs.counter_add("sim.retraces", backend=backend)
     return (
         np.asarray(fire)[:B],
         np.asarray(dead)[:B],

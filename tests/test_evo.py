@@ -232,7 +232,14 @@ def test_explorer_registry_lists_jax_nsga2():
 
 
 # ------------------------------------------------------------ observability
+def _within(inner, outer):
+    return outer["ts"] <= inner["ts"] and inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
+
+
 def test_generation_spans_and_retrace_counters(sobel_arch, monkeypatch, tmp_path):
+    """Each device call is an ``evo.execute`` span holding its dispatch,
+    wait and fetch; the finalization is one ``evo.finalize`` span holding
+    the host re-decode and the hypervolume; JAX's compiles are counted."""
     from repro import obs
 
     d = str(tmp_path / "obs")
@@ -251,9 +258,128 @@ def test_generation_spans_and_retrace_counters(sobel_arch, monkeypatch, tmp_path
     finally:
         obs.shutdown()
         obs.configure(None)
-    names = {e.get("name") for e in events}
+    spans = [e for e in events if e.get("t") == "span"]
+    names = {e["name"] for e in spans}
     assert "explorer.generation" in names
-    assert "evo.compile" in names  # first call of each jitted artifact
-    assert "evo.execute" in names  # steady-state calls
     assert "evo.tables" in names
-    assert any(e.get("name") == "evo.retraces" for e in events)
+    assert {n for n in names if n.startswith("evo.")} == {
+        "evo.tables", "evo.execute", "evo.dispatch", "evo.wait", "evo.fetch",
+        "evo.finalize", "evo.final_decode", "evo.hypervolume"}
+
+    executes = [s for s in spans if s["name"] == "evo.execute"]
+    assert executes
+    for ex in executes:
+        kids = sorted((s for s in spans if s["name"] in ("evo.dispatch", "evo.wait", "evo.fetch")
+                       and s["tid"] == ex["tid"] and _within(s, ex)), key=lambda s: s["ts"])
+        assert [k["name"] for k in kids] == ["evo.dispatch", "evo.wait", "evo.fetch"]
+    gens = [s for s in spans if s["name"] == "explorer.generation"]
+    assert len(gens) == 2
+    assert all(any(_within(ex, gen) for ex in executes) for gen in gens)
+
+    (fin,) = [s for s in spans if s["name"] == "evo.finalize"]
+    assert fin["ts"] >= max(gen["ts"] + gen["dur"] for gen in gens)
+    for name in ("evo.final_decode", "evo.hypervolume"):
+        (kid,) = [s for s in spans if s["name"] == name]
+        assert _within(kid, fin)
+    assert any(s["name"] == "engine.decode" and _within(s, fin) for s in spans)
+
+    compiles = [e for e in events if e.get("t") == "counter" and e["name"] == "jax.compiles"]
+    assert sum(e["value"] for e in compiles) >= 1
+    counters = {e["name"] for e in events if e.get("t") == "counter"}
+    assert not any(n.startswith("evo.") for n in counters)
+
+
+# ------------------------------------------------------ named device parts
+def _parts_in_hlo(lowered):
+    """The part names that appear in a lowered program's op metadata."""
+    import re
+
+    text = lowered.as_text(dialect="hlo", debug_info=True)
+    words = set()
+    for stack in re.findall(r'op_name="([^"]*)"', text):
+        words.update(re.findall(r"[A-Za-z_]\w*", stack))
+    return words & {"rank", "vary", "decode", "simulate"}
+
+
+def _relaxed_setup(strategy, seed=7):
+    from repro.core.apps import sobel
+    from repro.core.architecture import paper_architecture
+
+    problem = ExplorationProblem(
+        graph=sobel(), arch=paper_architecture(), strategy=strategy,
+        objectives=("sim_period", "memory", "core_cost"),
+    )
+    exp = get_explorer("jax_nsga2", evaluation="relaxed", population=8,
+                       offspring=4, generations=2, seed=seed)
+    calls = []
+    orig = exp._run_eval_plain
+
+    def tap(fn, args, label):
+        out = orig(fn, args, label)
+        calls.append((label, fn, args, out))
+        return out
+
+    exp._run_eval_plain = tap
+    return exp, problem, calls
+
+
+def test_device_steps_carry_their_part_names():
+    """Every jitted step names its parts (``jax.named_scope``), so a
+    profile can attribute each device op to rank, vary, decode or
+    simulate."""
+    import numpy as np
+
+    from repro.evo import ranking
+
+    exp, problem, calls = _relaxed_setup("Reference")
+    exp.explore(problem)
+    label, fused, args, _ = calls[0]
+    with jax.enable_x64(True):
+        assert _parts_in_hlo(fused.lower(*args)) == {"rank", "vary", "decode", "simulate"}
+
+    exp, problem, calls = _relaxed_setup("MRB_Explore")
+    exp.explore(problem)
+    steps = {label: (fn, args) for label, fn, args, _ in calls}
+    with jax.enable_x64(True):
+        fn, args = steps["vary"]
+        assert _parts_in_hlo(fn.lower(*args)) == {"rank", "vary"}
+        fn, args = steps["rank"]
+        assert _parts_in_hlo(fn.lower(*args)) == {"rank"}
+        (evaluator,) = [fn for key, fn in exp._eval_cache.items() if key[0] == (0,)]
+        genes = np.asarray(steps["vary"][1][1])
+        assert _parts_in_hlo(evaluator.lower(genes)) == {"decode", "simulate"}
+
+    ranking.parity_rank_crowd([(1.0, 2.0), (2.0, 1.0)])
+    keys = np.zeros((2, 2), np.int32)
+    assert _parts_in_hlo(ranking._DOMINATION_JIT.lower(keys)) == {"rank"}
+
+
+# The fused step's outputs at population 8, 4 offspring, seed 7, on Sobel
+# with (sim_period, memory, core_cost): the second generation's survivors.
+STORED_FUSED_F = [
+    [24378.0, 74605800.0, 4.5], [19004.0, 80782800.0, 7.0],
+    [18557.0, 91194600.0, 6.5], [31662.0, 74605800.0, 3.5],
+    [19903.0, 80782800.0, 4.5], [25697.0, 74605800.0, 4.0],
+    [22624.0, 97371600.0, 5.0], [25584.0, 74605800.0, 5.5],
+]
+STORED_FUSED_GENES = [
+    [0, 1, 4, 1, 3, 1, 1, 0, 22, 9, 17, 22, 1, 17, 14],
+    [0, 1, 1, 1, 2, 3, 3, 1, 4, 9, 19, 20, 17, 16, 12],
+    [0, 0, 4, 0, 1, 0, 1, 1, 22, 6, 13, 1, 17, 0, 22],
+    [0, 1, 2, 0, 3, 1, 3, 0, 22, 9, 17, 22, 17, 17, 14],
+    [0, 1, 2, 1, 3, 1, 1, 0, 22, 9, 17, 22, 11, 17, 1],
+    [0, 1, 4, 1, 3, 1, 1, 0, 22, 9, 17, 22, 11, 17, 14],
+    [0, 3, 4, 1, 0, 1, 3, 1, 23, 20, 3, 5, 10, 23, 4],
+    [0, 4, 4, 4, 0, 3, 4, 0, 14, 13, 0, 2, 18, 14, 8],
+]
+
+
+def test_fused_step_outputs_equal_a_stored_run():
+    """Naming the parts changes metadata only: the fused step's outputs
+    equal those stored from the step before it was named."""
+    exp, problem, calls = _relaxed_setup("Reference")
+    exp.explore(problem)
+    label, _, _, (genes, F) = calls[-1]
+    assert label == "gen" and len(calls) == 2
+    assert genes.tolist() == STORED_FUSED_GENES
+    assert F.tolist() == STORED_FUSED_F

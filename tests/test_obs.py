@@ -4,6 +4,7 @@ trace CLI, sim-backend spans, and a deterministic two-tenant
 claim-contention trace through the worker-pool scheduler."""
 import hashlib
 import json
+import os
 import random
 import time
 
@@ -275,12 +276,19 @@ def test_trace_cli_export_summary_and_min_cats(tmp_path, capsys):
 
 # ================================================================ sim spans
 def test_sim_backends_record_compile_execute_spans(obs_env):
+    """The batched simulator's execution is a ``sim.execute`` span, the
+    event simulator's a ``sim.events`` span, and JAX's own compile event
+    for a freshly built simulator is a ``jax.compiles`` count."""
     gt, arch = make_pipelined_sobel()
     res = random_decode(gt, arch, random.Random(0))
 
+    import jax  # noqa: F401  (the compile listener needs JAX imported)
+
     from repro.sim import SimConfig, batch_simulate, simulate
+    from repro.sim import vectorized
 
     cfg = SimConfig(trace=False)
+    vectorized._COMPILED.clear()  # a fresh jit wrapper: its first call compiles
     batch_simulate(gt, arch, [res.schedule], cfg)
     simulate(gt, arch, res.schedule, cfg)
     obs.flush()
@@ -290,10 +298,162 @@ def test_sim_backends_record_compile_execute_spans(obs_env):
     assert "sim.execute" in rows  # vectorized backend ran
     assert rows["sim.execute"]["count"] >= 1
     assert "sim.events" in rows  # exact backend ran
-    # A fresh process compiles; inside the full suite the module-level
-    # compiled-fn cache may already be warm — either signal is fine.
-    if "sim.compile" in rows:
-        assert summary["counters"].get("sim.cache_builds", 0) >= 1
+    assert summary["counters"].get("sim.cache_builds", 0) >= 1
+    assert summary["counters"].get("jax.compiles", 0) >= 1
+    assert {n for n in rows if n.startswith("sim.")} == {"sim.execute", "sim.events"}
+
+
+# ========================================================= profiler bridge
+def _profile(fn):
+    """Run ``fn`` under the profiler; the host events that are not ops, and
+    the op events, of the trace it wrote, as (name, start, end)."""
+    import glob
+    import os
+    import tempfile
+
+    import jax
+    from jax.profiler import ProfileData
+
+    with tempfile.TemporaryDirectory() as d:
+        jax.profiler.start_trace(d)
+        try:
+            fn()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(os.path.join(d, "plugins", "profile", "*", "*.xplane.pb"))
+        notes, ops = [], []
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for ev in line.events:
+                    rec = (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                    (ops if "hlo_op" in dict(ev.stats) else notes).append(rec)
+    return notes, ops
+
+
+def test_span_is_a_profiler_annotation_on_the_ops_clock(obs_env):
+    import jax
+    import jax.numpy as jnp
+
+    f = jax.jit(lambda x: jnp.tanh(x @ x).sum())
+    x = jnp.ones((256, 256))
+    f(x).block_until_ready()
+
+    def work():
+        with obs.span("explorer.generation", gen=7):
+            with obs.span("evo.execute"):
+                f(x).block_until_ready()
+
+    notes, ops = _profile(work)
+    (gen,) = [n for n in notes if n[0] == "explorer.generation"]
+    (ex,) = [n for n in notes if n[0] == "evo.execute"]
+    assert gen[1] <= ex[1] and ex[2] <= gen[2]
+    # The jitted call's ops ran inside the span, on the profile's one clock.
+    inside = [op for op in ops if ex[1] <= op[1] and op[2] <= ex[2]]
+    assert inside and any(op[0].startswith("dot") for op in inside)
+    obs.flush()
+    spans = [r for r in obs.iter_records(obs_env) if r.get("t") == "span"]
+    assert {s["name"] for s in spans} >= {"explorer.generation", "evo.execute"}
+
+
+def test_disabled_span_makes_no_annotation(monkeypatch):
+    import jax
+
+    monkeypatch.delenv(obs.OBS_ENV, raising=False)
+    obs.configure(False)
+    try:
+        def work():
+            with obs.span("evo.execute"):
+                jax.numpy.ones(4).block_until_ready()
+
+        notes, _ = _profile(work)
+    finally:
+        obs.configure(None)
+    assert not [n for n in notes if n[0] == "evo.execute"]
+
+
+def test_recorder_never_imports_jax(tmp_path):
+    """A process that never imported JAX (a pool worker) records spans,
+    on or off, without importing it."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from repro import obs\n"
+        "for on in (False, True):\n"
+        f"    obs.configure(on, {str(tmp_path)!r})\n"
+        "    with obs.span('explorer.generation', gen=1):\n"
+        "        with obs.span('evo.execute'):\n"
+        "            pass\n"
+        "    obs.counter_add('a.b')\n"
+        "obs.shutdown()\n"
+        "assert 'jax' not in sys.modules, 'repro.obs imported jax'\n"
+        "print(sum(1 for r in obs.iter_records() if r.get('t') == 'span'))\n"
+    )
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True,
+                         check=True, env=dict(os.environ, PYTHONPATH="src"))
+    assert out.stdout.split() == ["2"]
+
+
+def test_compile_listener_follows_configure(obs_env):
+    """JAX's compile events are recorded while telemetry is on, and not
+    after ``configure(False)``."""
+    import jax
+    import jax.numpy as jnp
+
+    def compiles():
+        obs.flush()
+        return sum(r["value"] for r in obs.iter_records(obs_env)
+                   if r.get("t") == "counter" and r["name"] == "jax.compiles")
+
+    obs.configure(None)
+    jax.jit(lambda x: x * 3.0 + 1.0)(jnp.ones(5)).block_until_ready()
+    seen = compiles()
+    assert seen >= 1
+    obs.configure(False)
+    jax.jit(lambda x: x * 5.0 - 2.0)(jnp.ones(5)).block_until_ready()
+    obs.configure(None)
+    assert compiles() == seen
+    jax.jit(lambda x: x * 7.0)(jnp.ones(5)).block_until_ready()
+    assert compiles() > seen
+
+
+def test_enabled_telemetry_keeps_other_scope_names_out_of_the_cache(tmp_path):
+    """JAX's persistent cache keys a program without its op metadata by
+    default, so the same program with its device parts named differently
+    loads the executable compiled under the old names.  While telemetry is on the
+    key holds the metadata, and the program compiles under its own."""
+    import subprocess
+    import sys
+
+    code = (
+        "import jax, jax.numpy as jnp\n"
+        "from repro import obs\n"
+        f"jax.config.update('jax_compilation_cache_dir', {str(tmp_path / 'cache')!r})\n"
+        "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)\n"
+        "jax.config.update('jax_persistent_cache_min_entry_size_bytes', 0)\n"
+        "def step(scoped):\n"
+        "    def f(x):\n"
+        "        if not scoped:\n"
+        "            return jnp.sin(x) * 2.0 + 1.0\n"
+        "        with jax.named_scope('rank'):\n"
+        "            return jnp.sin(x) * 2.0 + 1.0\n"
+        "    return f\n"
+        "def names(f):\n"
+        "    return jax.jit(f).lower(jnp.ones(8)).compile().as_text().count('rank')\n"
+        "obs.configure(False)\n"
+        "plain_names = names(step(False))\n"
+        "stale = names(step(True))\n"
+        f"obs.configure(True, {str(tmp_path / 'obs')!r})\n"
+        "fresh = names(step(True))\n"
+        "obs.configure(False)\n"
+        "print(plain_names, stale, fresh, jax.config.jax_compilation_cache_include_metadata_in_key)\n"
+    )
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True,
+                         check=True, env=dict(os.environ, PYTHONPATH="src"))
+    plain_names, stale, fresh, key_after = out.stdout.split()
+    assert (plain_names, stale, key_after) == ("0", "0", "False")
+    assert int(fresh) > 0
 
 
 # =============================================== two-tenant contention trace
