@@ -245,7 +245,10 @@ class JaxNSGA2Explorer:
         if tab is None:
             with obs.span("evo.tables", pattern=str(pattern)) as sp:
                 tab = DecodeTables(space, pattern, pipelined=pipelined)
-                sp.set(actors=tab.A, channels=tab.C)
+                sp.set(
+                    actors=tab.A, channels=tab.C, tmax=int(tab.static["Tmax"]),
+                    tasks=int(tab.static["n_tasks"].sum()),
+                )
             self._tables_cache[pattern] = tab
             while len(self._tables_cache) > self.max_patterns:
                 self._tables_cache.popitem(last=False)

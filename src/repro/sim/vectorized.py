@@ -10,11 +10,14 @@ arrays.  The hot path is throughput-shaped (ISSUE 4 rebuilt it):
   next task completion (no nested fixpoint/step loop towers, which
   serialize badly under ``vmap``);
 * a round is *data-parallel over the actors*: every actor's current task
-  is selected from a segment-packed dense task table (per-actor task
-  rows padded to ``Tmax``, fields one-hot packed) by one fused masked
-  reduction per table, and completions / enabling / priority arbitration
-  / state updates are masked array expressions — **no per-actor loop, no
-  ragged gathers, no scatters** anywhere in the compiled body;
+  is selected from segment-packed dense task planes (per-actor task rows
+  padded to ``Tmax``, each task's fields packed into a few int32 words:
+  one graph-derived code, its duration, its channel's γ, its route
+  bitmask) by masked reductions over ``Tmax``, unpacked with integer ops;
+  interconnect conflicts are bitmask ANDs; completions / enabling /
+  priority arbitration / state updates are masked array expressions —
+  **no per-actor loop, no ragged gathers, no scatters** anywhere in the
+  compiled body;
 * the firing-count target ``K`` is a *runtime* operand; the fire buffer
   is sized to the power-of-two bucket of the requested firings and batch
   sizes are bucketed to powers of two, so horizon-doubling reruns and
@@ -78,6 +81,9 @@ INT32_SAFE_HORIZON = 2**30
 BATCH_BACKENDS = ("vectorized", "pallas")
 
 _COMPILED: Dict[Tuple, object] = {}
+
+# Interconnects per int32 route bitmask word (the sign bit stays clear).
+_ROUTE_BITS = 31
 
 # Incremented every time a simulator function is (re)traced — the
 # retrace-regression test asserts structure-identical batches reuse the
@@ -163,11 +169,40 @@ def _structure_key(prog: SimProgram, cfg: SimConfig) -> Tuple:
     )
 
 
+def _pack_task_code(is_read: bool, is_write: bool, chan: int, slot: int, C: int) -> int:
+    """One task's graph-derived fields as one int32 of bit fields:
+    ``is_read | is_write << 1 | (chan + 1) << 2 | (slot + 1) << (2 + CB)``
+    with ``CB = C.bit_length()`` (the ``chan + 1`` field holds 0..C), where
+    ``chan`` / ``slot`` are −1 for a task without a channel / reader slot.
+    Padding slots hold 0, which unpacks to the all-zero descriptor; shifts
+    and masks unpack it, no integer division."""
+    return (
+        int(is_read) | int(is_write) << 1 | (chan + 1) << 2
+        | (slot + 1) << (2 + C.bit_length())
+    )
+
+
+def _unpack_task_code(code, c_iota, s_iota, C: int):
+    """Inverse of :func:`_pack_task_code` on a vector of codes, with integer
+    ops only (numpy or jnp alike): ``(is_read, is_write, chan one-hot,
+    slot one-hot)``, the one-hots compared against ``c_iota = 0..C-1`` and
+    ``s_iota = 0..R-1``."""
+    cb = C.bit_length()
+    chan = ((code >> 2) & ((1 << cb) - 1)) - 1
+    slot = (code >> (2 + cb)) - 1
+    return (
+        (code & 1) > 0,
+        (code & 2) > 0,
+        chan[:, None] == c_iota[None],
+        slot[:, None] == s_iota[None],
+    )
+
+
 def _lower_batch(progs: Sequence[SimProgram]):
     """Static structure arrays (graph-derived, shared) + batched arrays
     (binding-derived, per phenotype), in segment-packed dense layout: every
     per-task table is padded to ``Tmax`` tasks per actor so the step body
-    can select the current task with a one-hot mask instead of a ragged
+    can select the current task with a masked reduction instead of a ragged
     gather."""
     p0 = progs[0]
     actors = p0.actors
@@ -180,10 +215,14 @@ def _lower_batch(progs: Sequence[SimProgram]):
     Tmax = max(len(p0.tasks[a]) for a in actors)
 
     n_tasks = np.array([len(p0.tasks[a]) for a in actors], np.int32)
-    # Graph-derived per-task fields, packed so the current-task descriptor
-    # of ALL actors is one fused one-hot reduction: columns are
-    # [is_read, is_write, chan one-hot (C), reader-slot one-hot (R)].
+    if _pack_task_code(True, True, C - 1, R - 1, C) > np.iinfo(np.int32).max:
+        raise ValueError(f"task codes of {C} channels x {R} reader slots overflow int32")
+    # Graph-derived per-task fields as one-hot columns [is_read, is_write,
+    # chan one-hot (C), reader-slot one-hot (R)] — read once by the device
+    # decode's ASAP pass — and the same fields as one int32 code per task,
+    # which the simulator's rounds select (see _pack_task_code).
     ts_tab = np.zeros((A, Tmax, 2 + C + R), np.int32)
+    task_code = np.zeros((A, Tmax), np.int32)
     for ai, a in enumerate(actors):
         for ti, t in enumerate(p0.tasks[a]):
             ts_tab[ai, ti, 0] = t.kind == READ
@@ -192,6 +231,11 @@ def _lower_batch(progs: Sequence[SimProgram]):
                 ts_tab[ai, ti, 2 + c_idx[t.channel]] = 1
             if t.reader_slot >= 0:
                 ts_tab[ai, ti, 2 + C + t.reader_slot] = 1
+            task_code[ai, ti] = _pack_task_code(
+                t.kind == READ, t.kind == WRITE,
+                c_idx[t.channel] if t.channel is not None else -1,
+                t.reader_slot, C,
+            )
 
     reader_mask = np.zeros((C, R), bool)
     delay = np.zeros(C, np.int32)
@@ -210,8 +254,9 @@ def _lower_batch(progs: Sequence[SimProgram]):
                 outmask[ai, c_idx[t.channel]] = True
 
     B = len(progs)
-    # Binding-derived per-task fields, packed the same way: [duration,
-    # route occupancy (H)] — batched because bindings differ per phenotype.
+    # Binding-derived per-task fields: [duration, route occupancy (H)] —
+    # batched because bindings differ per phenotype (the simulator packs
+    # the routes into bitmask words once per call).
     # Cores are remapped per element to a compact 0..A-1 index space (an
     # element binds at most A distinct cores, usually far fewer than the
     # architecture has) so the per-round core-arbitration arrays stay
@@ -234,7 +279,7 @@ def _lower_batch(progs: Sequence[SimProgram]):
 
     static = dict(
         A=A, C=C, P=A, H=H, R=R, Tmax=Tmax,
-        n_tasks=n_tasks, ts_tab=ts_tab,
+        n_tasks=n_tasks, ts_tab=ts_tab, task_code=task_code,
         reader_mask=reader_mask, delay=delay, inmask=inmask, outmask=outmask,
     )
     batched = dict(tb=tb_tab, core_oh=core_oh, gamma=gamma)
@@ -263,9 +308,11 @@ def build_simulate_one(static, ports: Optional[int], k_max: int):
     statically ``(A, k_max)``.  Each loop iteration is one synchronous
     phased round of the model discipline, computed *data-parallel over the
     actors*: the current task of every actor is selected from the
-    segment-packed dense task table with one fused one-hot reduction per
-    packed table, completions/candidates/arbitration are masked array
-    expressions, and there is no per-actor loop, gather or scatter
+    segment-packed dense task planes (one packed int32 code per task for
+    the graph-derived fields, plus duration, channel-γ and route-bitmask
+    planes packed from the call's operands once per call) with masked
+    reductions over ``Tmax``, completions/candidates/arbitration are masked
+    array expressions, and there is no per-actor loop, gather or scatter
     anywhere — XLA fuses a round into a few dozen kernels regardless of
     actor count.  Returns ``(simulate_one, tables)`` where ``tables`` is
     the tuple of graph-derived structure arrays ``simulate_one`` expects
@@ -280,9 +327,11 @@ def build_simulate_one(static, ports: Optional[int], k_max: int):
     A = static["A"]
     C = static["C"]
     R = static["R"]
+    H = static["H"]
     Tmax = static["Tmax"]
+    W = max(1, -(-H // _ROUTE_BITS))  # route bitmask words per task
     tables = (
-        static["ts_tab"],           # (A,Tmax,2+C+R)
+        static["task_code"],        # (A,Tmax) packed graph-derived fields
         static["n_tasks"],          # (A,)
         static["reader_mask"],      # (C,R)
         static["delay"],            # (C,)
@@ -295,9 +344,12 @@ def build_simulate_one(static, ports: Optional[int], k_max: int):
     def simulate_one(tables, tb, core_oh, gamma, K):
         global _TRACE_COUNT
         _TRACE_COUNT += 1
-        ts_tab, n_tasks, reader_mask, delay, inmask, outmask = tables
+        task_code, n_tasks, reader_mask, delay, inmask, outmask = tables
         aidx = jnp.arange(A, dtype=jnp.int32)
         t_iota = jnp.arange(Tmax, dtype=jnp.int32)
+        c_iota = jnp.arange(C, dtype=jnp.int32)
+        s_iota = jnp.arange(R, dtype=jnp.int32)
+        b_iota = jnp.arange(_ROUTE_BITS, dtype=jnp.int32)
         k_iota = jnp.arange(int(k_max), dtype=jnp.int32)
         # lower_tri[i, j] ⇔ j strictly precedes i in arbitration order
         lower_tri = aidx[:, None] > aidx[None, :]
@@ -309,28 +361,48 @@ def build_simulate_one(static, ports: Optional[int], k_max: int):
                 0,
             )                                                      # (C,R)
 
+        def words_of(mask):
+            # (..., H) bool → (..., W) int32: bit b of word w ⇔ element 31·w + b.
+            mask = jnp.pad(mask, [(0, 0)] * (mask.ndim - 1) + [(0, W * _ROUTE_BITS - H)])
+            return jnp.sum(
+                jnp.where(mask.reshape(mask.shape[:-1] + (W, _ROUTE_BITS)), 1 << b_iota, 0),
+                axis=-1, dtype=jnp.int32,
+            )
+
+        # One (Tmax,A) int32 plane per packed per-task field: graph code,
+        # duration, γ of the task's channel, route bitmask words (the
+        # interconnects the task occupies).  Built once per call; every
+        # round selects all planes with sibling masked reductions over
+        # Tmax, a major axis.
+        chan_tab = _unpack_task_code(task_code.reshape(A * Tmax), c_iota, s_iota, C)[2]
+        gamma_tab = jnp.sum(jnp.where(chan_tab, gamma[None], 0), axis=1, dtype=jnp.int32)
+        route_words = words_of(tb[:, :, 1:] > 0)                   # (A,Tmax,W)
+        planes = [task_code.T, tb[:, :, 0].T, gamma_tab.reshape(A, Tmax).T] + [
+            route_words[:, :, w].T for w in range(W)
+        ]
+
         def descriptor(cur):
-            # Current-task descriptor for every actor: two fused one-hot
-            # reductions over the packed dense task tables (graph-derived
-            # and binding-derived columns).  cur == n_tasks between
-            # windows — the all-zero one-hot then yields don't-care
-            # fields, gated out by in_w everywhere.
+            # Current-task descriptor for every actor: a masked reduction
+            # over Tmax of each packed plane, unpacked by integer ops (see
+            # _unpack_task_code).  cur == n_tasks between windows — the
+            # all-zero selection then yields don't-care fields, gated out
+            # by in_w everywhere.
             cur_oh = t_iota[None, :] == cur[:, None]               # (A,Tmax)
-            ts = jnp.sum(jnp.where(cur_oh[:, :, None], ts_tab, 0), axis=1, dtype=jnp.int32)
-            tbv = jnp.sum(jnp.where(cur_oh[:, :, None], tb, 0), axis=1, dtype=jnp.int32)
+            sel = [
+                jnp.sum(jnp.where(cur_oh.T, p, 0), axis=0, dtype=jnp.int32) for p in planes
+            ]                                                      # (A,) each
             d = {}
-            d["is_read"] = ts[:, 0] > 0                            # (A,)
-            d["is_write"] = ts[:, 1] > 0
-            c_oh = ts[:, 2:2 + C] > 0                              # (A,C)
-            s_oh = ts[:, 2 + C:] > 0                               # (A,R)
+            d["is_read"], d["is_write"], c_oh, s_oh = _unpack_task_code(
+                sel[0], c_iota, s_iota, C
+            )                                                      # (A,C), (A,R)
             d["c_oh"] = c_oh
-            d["dur_t"] = tbv[:, 0]
-            d["route_t"] = tbv[:, 1:] > 0                          # (A,H)
+            d["dur_t"] = sel[1]
+            d["gamma_c"] = jnp.maximum(sel[2], 1)
+            d["route_w"] = jnp.stack(sel[3:])                      # (W,A)
+            bits = (d["route_w"].T[:, :, None] >> b_iota) & 1      # (A,W,31)
+            d["route_t"] = bits.reshape(A, W * _ROUTE_BITS)[:, :H] > 0  # (A,H)
             d["cs_mask"] = c_oh[:, :, None] & s_oh[:, None, :]     # (A,C,R)
             d["timed"] = d["dur_t"] > 0
-            d["gamma_c"] = jnp.maximum(
-                jnp.sum(jnp.where(c_oh, gamma[None], 0), axis=1, dtype=jnp.int32), 1
-            )
             return d
 
         def read_adv(cs_mask, gamma_c, avail, rho):
@@ -420,8 +492,8 @@ def build_simulate_one(static, ports: Optional[int], k_max: int):
 
             d = descriptor(cur)
             is_read, is_write = d["is_read"], d["is_write"]
-            c_oh, route_t, timed, dur_t = (
-                d["c_oh"], d["route_t"], d["timed"], d["dur_t"]
+            c_oh, route_t, route_w, timed, dur_t = (
+                d["c_oh"], d["route_t"], d["route_w"], d["timed"], d["dur_t"]
             )
             avail_t, rho_adv = read_adv(d["cs_mask"], d["gamma_c"], avail, rho)
             free_c = jnp.sum(jnp.where(c_oh, free[None], 0), axis=1, dtype=jnp.int32)
@@ -429,7 +501,7 @@ def build_simulate_one(static, ports: Optional[int], k_max: int):
                 (in_w & ~running)
                 & (~is_read | (avail_t >= 1))
                 & (~is_write | (free_c >= 1))
-                & ~jnp.any(route_t & (ic_busy[None] > t), axis=1)
+                & jnp.all((route_w & words_of(ic_busy > t)[:, None]) == 0, axis=0)
             )
             if ports is None:
                 surv = cand
@@ -445,7 +517,7 @@ def build_simulate_one(static, ports: Optional[int], k_max: int):
                 surv = cand & (~chan_cand | (active_c + rank < ports))
             # A start is deferred (next round, same t) when a higher-
             # priority surviving timed candidate shares an interconnect.
-            share = jnp.any(route_t[:, None, :] & route_t[None, :, :], axis=2)
+            share = jnp.any((route_w[:, :, None] & route_w[:, None, :]) != 0, axis=0)
             blocked = jnp.any(lower_tri & (surv & timed)[None, :] & share, axis=1)
             win = surv & ~blocked
 

@@ -261,7 +261,8 @@ def test_generation_spans_and_retrace_counters(sobel_arch, monkeypatch, tmp_path
     spans = [e for e in events if e.get("t") == "span"]
     names = {e["name"] for e in spans}
     assert "explorer.generation" in names
-    assert "evo.tables" in names
+    (tables,) = [s["attrs"] for s in spans if s["name"] == "evo.tables"]
+    assert tables["actors"] <= tables["tasks"] <= tables["actors"] * tables["tmax"]
     assert {n for n in names if n.startswith("evo.")} == {
         "evo.tables", "evo.execute", "evo.dispatch", "evo.wait", "evo.fetch",
         "evo.finalize", "evo.final_decode", "evo.hypervolume"}

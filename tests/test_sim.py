@@ -10,6 +10,7 @@ import json
 import math
 import random
 
+import numpy as np
 import pytest
 
 from conftest import make_pipelined_sobel, random_decode
@@ -23,6 +24,8 @@ from repro.core import (
     pipeline_delays,
     substitute_mrbs,
 )
+from repro.core.apps import multicamera, sobel
+from repro.core.architecture import paper_architecture
 from repro.core.caps_hms import DecodeResult, decode_via_heuristic
 from repro.core.ilp import ExactResult
 from repro.core.schedule import (
@@ -47,7 +50,7 @@ from repro.sim import (
     trace_count,
 )
 from repro.sim.model import lower_phenotype, predict_horizon
-from repro.sim.vectorized import INT32_SAFE_HORIZON
+from repro.sim.vectorized import INT32_SAFE_HORIZON, _unpack_task_code, lower_structure
 
 NO_TRACE = SimConfig(trace=False)
 
@@ -55,6 +58,13 @@ NO_TRACE = SimConfig(trace=False)
 # ------------------------------------------------------------ helpers
 # (_pipelined_sobel / _random_decode moved to conftest.py: imported above
 # as plain functions so the @given property tests can reach them too.)
+def _paper_app(app, xi):
+    """A paper application on the paper platform, every multicast actor
+    substituted by its MRB (ξ = 1) or kept (ξ = 0), with §VI delays."""
+    g, arch = app(), paper_architecture()
+    return pipeline_delays(substitute_mrbs(g, {a: xi for a in multicast_actors(g)})), arch
+
+
 def _lower_bound(gt, arch, sched):
     attach_binding(gt, sched.channel_binding)
     rt, wt = comm_times(gt, arch, sched.actor_binding, sched.channel_binding)
@@ -202,6 +212,39 @@ def test_pallas_backend_matches_events_on_sobel_batch():
         assert e.fire_times == v.fire_times
         assert e.period == v.period
         assert e.deadlocked == v.deadlocked
+
+
+@pytest.mark.parametrize("xi", [0, 1])
+def test_vectorized_matches_events_on_multicamera(xi):
+    """Multicamera holds a 53-task actor (ξ = 0) and two-reader MRBs
+    (ξ = 1): the packed per-task codes, durations and route bitmasks the
+    rounds select reproduce the events backend's firing sequences."""
+    gt, arch = _paper_app(multicamera, xi)
+    rng = random.Random(21 + xi)
+    scheds = [random_decode(gt, arch, rng).schedule for _ in range(2)]
+    cfg = SimConfig(trace=False, iterations=8, max_iterations=8)
+    ev = [simulate(gt, arch, s, cfg) for s in scheds]
+    vec = batch_simulate(gt, arch, scheds, cfg)
+    for e, v in zip(ev, vec):
+        assert e.fire_times == v.fire_times
+        assert e.period == v.period
+        assert e.deadlocked == v.deadlocked
+
+
+@pytest.mark.parametrize("app,xi", [(multicamera, 0), (multicamera, 1), (sobel, 1)])
+def test_task_code_unpacks_to_one_hot_fields(app, xi):
+    """Every (actor, task) code the simulator selects, padding included,
+    unpacks to exactly the one-hot fields of ``ts_tab``."""
+    gt, arch = _paper_app(app, xi)
+    prog = lower_phenotype(gt, arch, random_decode(gt, arch, random.Random(0)).schedule)
+    static, _ = lower_structure(prog)
+    A, C, R, Tmax = (static[k] for k in ("A", "C", "R", "Tmax"))
+    code = static["task_code"].reshape(A * Tmax)
+    is_read, is_write, c_oh, s_oh = _unpack_task_code(
+        code, np.arange(C), np.arange(R), C
+    )
+    unpacked = np.concatenate([is_read[:, None], is_write[:, None], c_oh, s_oh], axis=1)
+    assert np.array_equal(unpacked, static["ts_tab"].reshape(A * Tmax, 2 + C + R) > 0)
 
 
 @pytest.mark.filterwarnings("ignore:Some donated buffers were not usable")

@@ -8,6 +8,8 @@ The topology is described inside a fixture (never at import), and the
 persistent compile cache is off around these compiles: their entries
 could not be read back without a chip.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -109,6 +111,39 @@ def test_vectorized_simulator_compiles_at_batch_64(shape_of, mcam_tables):
     static = mcam_tables[0].static
     fn = _build_sim(static, SimConfig(), 16, donate=False)
     fn.lower(*_sim_operands(shape_of, static, 64)).compile()
+
+
+def _while_body_shapes(hlo: str):
+    """Result shapes of the instructions in every ``while`` body of an HLO
+    module's text, as ``(name, dims)``."""
+    shapes = []
+    for body in re.findall(r"while\(.*?body=%?([\w.\-]+)", hlo):
+        start = hlo.index(f"%{body} ")
+        text = hlo[start:hlo.index("\n}\n", start)]
+        for name, dims in re.findall(r"^\s*(?:ROOT )?%?(\S+) = \(?\w+\[([\d,]*)\]", text, re.M):
+            shapes.append((name, tuple(int(d) for d in dims.split(",") if d)))
+    return shapes
+
+
+@pytest.mark.parametrize("xi", [0, 1])
+def test_simulator_round_selects_no_one_hot_task_row(shape_of, xi):
+    """The Multicamera simulator at batch 64: no op in the round loop
+    outputs a row of the one-hot task table (minor dimension 2+C+R) — each
+    actor's current task is selected as a few packed int32 words."""
+    from repro.evo.decode import DecodeTables
+    from repro.evo.encoding import PopulationLayout
+    from repro.sim.model import SimConfig
+    from repro.sim.vectorized import _build_sim
+
+    space = GenotypeSpace(multicamera(), paper_architecture())
+    layout = PopulationLayout(space, "explore")
+    static = DecodeTables(space, (xi,) * layout.n_xi).static
+    fn = _build_sim(static, SimConfig(), 32, donate=False)
+    hlo = fn.lower(*_sim_operands(shape_of, static, 64)).compile().as_text()
+    shapes = _while_body_shapes(hlo)
+    assert len(shapes) > 20, "no while body found"
+    row = 2 + static["C"] + static["R"]
+    assert [(n, d) for n, d in shapes if d and d[-1] == row] == []
 
 
 def test_sim_step_kernel_round_body_is_refused(shape_of, mcam_tables):
