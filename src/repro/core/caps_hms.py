@@ -11,17 +11,24 @@ order) at the earliest start s'_a ∈ [s_a, s_a + P) such that
 wrapping occupancy into [0, P) via f_wrap.  On failure for every candidate
 start, the decoder retries with P+1 (paper-faithful linear period search).
 
-Efficiency note (beyond-paper, semantics-preserving): instead of probing
+Efficiency notes (beyond-paper, semantics-preserving): instead of probing
 every integer s'_a the search jumps to the end of the blocking busy
 interval, which visits exactly the same sequence of *feasible* candidates
 the paper's loop would accept, in O(#busy intervals) instead of O(P).
+What does not depend on s'_a (the window's length, each communication
+task's offset, duration and route) is laid out once per binding, so a
+candidate costs one allocation-free ``UtilizationSet.busy_end`` query per
+resource; a commit coalesces each new interval with its neighbours only,
+and the ready queue is a heap.
 """
 from __future__ import annotations
 
+import heapq
 import math
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Set, Tuple
+from typing import Dict, List, Optional, Set, Tuple
 
+from .. import obs
 from .architecture import ArchitectureGraph
 from .binding import determine_channel_bindings
 from .graph import ApplicationGraph, topological_priorities
@@ -29,7 +36,6 @@ from .schedule import (
     Schedule,
     TaskTimes,
     UtilizationSet,
-    actor_window,
     attach_binding,
     comm_times,
     f_wrap,
@@ -88,17 +94,30 @@ def _advance_past(period: int, s_abs: int, offset: int, busy_end: int) -> int:
     return s_abs + (delta if delta > 0 else period)
 
 
+@dataclass(frozen=True)
+class _ActorLayout:
+    """One actor's window under a binding, relative to its window start s:
+    reads packed from 0, the execution at ``t_in``, writes after it."""
+
+    core: str
+    t_in: int
+    t_win: int  # τ'_a = τ_EI + τ_a + τ_EO
+    comms: Tuple[Tuple[int, int, Tuple[str, ...]], ...]  # (offset, τ, hops), τ > 0
+    reads: Tuple[Tuple[Tuple[str, str], int], ...]  # ((c, a), offset)
+    writes: Tuple[Tuple[Tuple[str, str], int], ...]  # ((a, c), offset)
+
+
 @dataclass
 class _Ctx:
     """Per-(binding, decisions) invariants hoisted out of the period search."""
 
     read_tau: Dict[Tuple[str, str], int]
     write_tau: Dict[Tuple[str, str], int]
-    route_r: Dict[Tuple[str, str], List[str]]
     prio: Dict[str, int]
-    windows: Dict[str, Tuple[int, int, int]]  # (τ_EI, τ_a, τ_EO)
-    in_ch: Dict[str, List[str]]
-    out_ch: Dict[str, List[str]]
+    layout: Dict[str, _ActorLayout]
+    # Zero-delay dependencies, the only ones the placement order follows.
+    preds0: Dict[str, Tuple[str, ...]]  # producers of a's zero-delay inputs
+    succs0: Dict[str, Tuple[str, ...]]  # consumers of a's zero-delay outputs
 
 
 def _build_ctx(
@@ -109,24 +128,42 @@ def _build_ctx(
 ) -> _Ctx:
     attach_binding(g, channel_binding)
     read_tau, write_tau = comm_times(g, arch, actor_binding, channel_binding)
-    route_r: Dict[Tuple[str, str], List[str]] = {}
-    for c in g.channels:
-        mem = channel_binding[c]
-        for r in g.consumers[c]:
-            route_r[(c, r)] = arch.route_interconnects(actor_binding[r], mem)
-        p = g.producer[c]
-        route_r[(p, c)] = arch.route_interconnects(actor_binding[p], mem)
-    in_ch = {a: g.in_channels(a) for a in g.actors}
-    out_ch = {a: g.out_channels(a) for a in g.actors}
-    windows = {}
+    layout: Dict[str, _ActorLayout] = {}
+    preds0: Dict[str, Tuple[str, ...]] = {}
+    succs0: Dict[str, Tuple[str, ...]] = {}
     for a in g.actors:
-        t_in = sum(read_tau[(c, a)] for c in in_ch[a])
-        t_out = sum(write_tau[(a, c)] for c in out_ch[a])
-        ctype = arch.cores[actor_binding[a]].ctype
-        windows[a] = (t_in, g.actors[a].exec_times[ctype], t_out)
-    return _Ctx(
-        read_tau, write_tau, route_r, topological_priorities(g), windows, in_ch, out_ch
-    )
+        core = actor_binding[a]
+        comms: List[Tuple[int, int, Tuple[str, ...]]] = []
+        reads: List[Tuple[Tuple[str, str], int]] = []
+        writes: List[Tuple[Tuple[str, str], int]] = []
+        off = 0
+        for c in g.in_channels(a):
+            tau = read_tau[(c, a)]
+            reads.append(((c, a), off))
+            if tau > 0:
+                hops = arch.route_interconnects(core, channel_binding[c])
+                comms.append((off, tau, tuple(hops)))
+            off += tau
+        t_in = off
+        off += g.actors[a].exec_times[arch.cores[core].ctype]
+        for c in g.out_channels(a):
+            tau = write_tau[(a, c)]
+            writes.append(((a, c), off))
+            if tau > 0:
+                hops = arch.route_interconnects(core, channel_binding[c])
+                comms.append((off, tau, tuple(hops)))
+            off += tau
+        layout[a] = _ActorLayout(core, t_in, off, tuple(comms), tuple(reads), tuple(writes))
+        preds0[a] = tuple(
+            g.producer[c] for c in g.in_channels(a) if g.channels[c].delay == 0
+        )
+        succs0[a] = tuple(
+            r
+            for c in g.out_channels(a)
+            if g.channels[c].delay == 0
+            for r in g.consumers[c]
+        )
+    return _Ctx(read_tau, write_tau, topological_priorities(g), layout, preds0, succs0)
 
 
 def caps_hms(
@@ -140,140 +177,105 @@ def caps_hms(
     """Algorithm 5.  Returns task start times on success, None on failure."""
     if ctx is None:
         ctx = _build_ctx(g, arch, actor_binding, channel_binding)
-    read_tau, write_tau = ctx.read_tau, ctx.write_tau
-    route_r, prio = ctx.route_r, ctx.prio
+    times, candidates = _place(g, arch, period, ctx)
+    obs.counter_add("caps_hms.candidates", candidates)
+    return times
 
+
+def _place(
+    g: ApplicationGraph, arch: ArchitectureGraph, period: int, ctx: _Ctx
+) -> Tuple[Optional[TaskTimes], int]:
+    """One CAPS-HMS attempt at ``period``: (task times or None, number of
+    candidate starts tested)."""
+    prio, layout, preds0, succs0 = ctx.prio, ctx.layout, ctx.preds0, ctx.succs0
     util: Dict[str, UtilizationSet] = {r: UtilizationSet() for r in arch.schedulable_resources()}
     times = TaskTimes()
+    actor_start, read_start, write_start = times.actor_start, times.read_start, times.write_start
     s_min: Dict[str, int] = {a: 0 for a in g.actors}  # earliest start (deps)
+    candidates = 0
 
-    def ready_initial() -> List[str]:
-        out = []
-        for a in g.actors:
-            if all(g.channels[c].delay >= 1 for c in ctx.in_ch[a]):
-                out.append(a)
-        return out
-
+    # Ready actors, popped by highest priority then name.
+    ready = [(-prio[a], a) for a in g.actors if not preds0[a]]
+    heapq.heapify(ready)
+    queued: Set[str] = {a for _, a in ready}
     scheduled: Set[str] = set()
-    ready: List[str] = ready_initial()
-
-    def newly_ready(a_fired: str) -> List[str]:
-        out = []
-        for c in ctx.out_ch[a_fired]:
-            if g.channels[c].delay >= 1:
-                continue
-            for a2 in g.consumers[c]:
-                if a2 in scheduled or a2 in ready or a2 in out:
-                    continue
-                ok = True
-                for cin in ctx.in_ch[a2]:
-                    if g.channels[cin].delay >= 1:
-                        continue
-                    if g.producer[cin] not in scheduled:
-                        ok = False
-                        break
-                if ok:
-                    out.append(a2)
-        return out
 
     while ready:
-        ready.sort(key=lambda a: (-prio[a], a))
-        a = ready.pop(0)
-        p = actor_binding[a]
-        reads = [(c, a) for c in ctx.in_ch[a]]
-        writes = [(a, c) for c in ctx.out_ch[a]]
-        t_in, t_ex, t_out = ctx.windows[a]
-        t_win = t_in + t_ex + t_out
+        a = heapq.heappop(ready)[1]
+        lay = layout[a]
+        t_win, comms = lay.t_win, lay.comms
         if t_win > period:
-            return None  # cannot fit even alone
+            return None, candidates  # cannot fit even alone
+        core_util = util[lay.core]
 
-        placed = False
         s = s_min[a]
-        limit = s_min[a] + period
+        limit = s + period
         while s < limit:
+            candidates += 1
             # Core window free?
-            pieces = f_wrap(period, s, t_win)
-            hit = util[p].conflict(pieces)
-            if hit is not None:
-                s = _advance_past(period, s, 0, hit[1])
+            busy = core_util.busy_end(period, s, t_win)
+            if busy >= 0:
+                s = _advance_past(period, s, 0, busy)
                 continue
             # Interconnects free for each comm task at its packed offset?
-            off = 0
-            comm_offsets: List[Tuple[Tuple[str, str], int, int]] = []
-            for t in reads:
-                comm_offsets.append((t, off, read_tau[t]))
-                off += read_tau[t]
-            off += t_ex
-            for t in writes:
-                comm_offsets.append((t, off, write_tau[t]))
-                off += write_tau[t]
-            conflict_jump: Optional[int] = None
-            for t, o, tau in comm_offsets:
-                if tau <= 0:
-                    continue
-                tp = f_wrap(period, s + o, tau)
-                for h in route_r[t]:
-                    hit = util[h].conflict(tp)
-                    if hit is not None:
-                        cand = _advance_past(period, s, o, hit[1])
-                        if conflict_jump is None or cand < conflict_jump:
-                            conflict_jump = cand
+            jump = -1
+            for o, tau, hops in comms:
+                for h in hops:
+                    busy = util[h].busy_end(period, s + o, tau)
+                    if busy >= 0:
+                        jump = _advance_past(period, s, o, busy)
                         break
-                if conflict_jump is not None:
+                if jump >= 0:
                     break
-            if conflict_jump is not None:
-                s = max(conflict_jump, s + 1)
-                continue
+            if jump < 0:
+                break
+            s = jump
+        else:
+            return None, candidates
 
-            # Commit (Lines 17-21).
-            util[p].add(pieces)
-            for t, o, tau in comm_offsets:
-                if tau <= 0:
-                    continue
-                for h in route_r[t]:
-                    util[h].add(f_wrap(period, s + o, tau))
-            times.actor_start[a] = s + t_in
-            # Record comm starts (reads then writes, packed; zero-time comms
-            # get the packed position too for capacity accounting).
-            off = 0
-            for t in reads:
-                times.read_start[t] = s + off
-                off += read_tau[t]
-            off += t_ex
-            for t in writes:
-                times.write_start[t] = s + off
-                off += write_tau[t]
-            end = s + t_win
-            for c in ctx.out_ch[a]:
-                if g.channels[c].delay == 0:
-                    for a2 in g.consumers[c]:
-                        if a2 not in scheduled:
-                            s_min[a2] = max(s_min[a2], end)
-            scheduled.add(a)
-            ready.extend(newly_ready(a))
-            placed = True
-            break
-        if not placed:
-            return None
+        # Commit (Lines 17-21).
+        core_util.add(f_wrap(period, s, t_win))
+        for o, tau, hops in comms:
+            pieces = f_wrap(period, s + o, tau)
+            for h in hops:
+                util[h].add(pieces)
+        actor_start[a] = s + lay.t_in
+        # Comm starts (reads then writes, packed; zero-time comms get the
+        # packed position too for capacity accounting).
+        for t, o in lay.reads:
+            read_start[t] = s + o
+        for t, o in lay.writes:
+            write_start[t] = s + o
+        scheduled.add(a)
+        end = s + t_win
+        for a2 in succs0[a]:
+            if a2 in scheduled:
+                continue
+            if s_min[a2] < end:
+                s_min[a2] = end
+            if a2 not in queued and all(p in scheduled for p in preds0[a2]):
+                queued.add(a2)
+                heapq.heappush(ready, (-prio[a2], a2))
 
     if len(scheduled) != len(g.actors):
         # Unreachable actors (cyclic zero-delay parts) — treat as failure.
-        return None
+        return None, candidates
 
     # Cross-iteration dependency guard (Eq. 16 for δ ≥ 1 channels).  The
     # paper's Line 20 only propagates zero-delay dependencies; with initial
     # tokens a consumer of higher priority can be placed more than δ
     # periods before its producer's write completes.  Rejecting here makes
     # the decoder retry with a larger P, which absorbs the drift.
+    write_tau = ctx.write_tau
     for c in g.channels:
         prod = g.producer[c]
-        s_w = times.write_start[(prod, c)]
+        s_w = write_start[(prod, c)]
         tau_w = write_tau[(prod, c)]
         delta = g.channels[c].delay
         for r in g.consumers[c]:
-            if s_w + tau_w - period * delta > times.read_start[(c, r)]:
-                return None
-    return times
+            if s_w + tau_w - period * delta > read_start[(c, r)]:
+                return None, candidates
+    return times, candidates
 
 
 def _search_period(
@@ -361,6 +363,7 @@ def decode_via_heuristic(
             g, arch, actor_binding, beta_c, lb, cap, period_search, ctx
         )
         tried += n
+        obs.counter_add("caps_hms.attempts", n)
         if times is None:
             return DecodeResult(None, False, tried)
 

@@ -47,7 +47,8 @@ def f_wrap(period: int, start: int, dur: int) -> List[Tuple[int, int]]:
 
 
 class UtilizationSet:
-    """Sorted disjoint occupied intervals within [0, P).
+    """Sorted disjoint occupied intervals within [0, P), coalesced so that
+    no two touch.
 
     Supports O(log n) overlap queries and conflict reporting for the
     jump-ahead candidate search used by both schedulers.
@@ -81,23 +82,52 @@ class UtilizationSet:
                 return hit
         return None
 
+    def busy_end(self, period: int, start: int, dur: int) -> int:
+        """End of the first occupied interval that ``f_wrap(period, start,
+        dur)`` overlaps, or -1: ``conflict(f_wrap(period, start, dur))[1]``
+        without building the pieces or the hit."""
+        starts = self.starts
+        if dur <= 0 or not starts:
+            return -1
+        if dur >= period:
+            b, e, wrap = 0, period, 0
+        else:
+            b = start % period
+            e = b + dur
+            wrap = e - period
+            if wrap > 0:
+                e = period
+        ends = self.ends
+        i = bisect.bisect_right(starts, b) - 1
+        if i >= 0 and ends[i] > b:
+            return ends[i]
+        i += 1
+        if i < len(starts) and starts[i] < e:
+            return ends[i]
+        if wrap > 0 and starts[0] < wrap:
+            return ends[0]
+        return -1
+
     def add(self, pieces: Sequence[Tuple[int, int]]) -> None:
+        """Occupy each piece, coalescing it with the intervals it overlaps or
+        touches; pieces may overlap what is already occupied."""
+        starts, ends = self.starts, self.ends
         for b, e in pieces:
             if b >= e:
                 continue
-            i = bisect.bisect_left(self.starts, b)
-            self.starts.insert(i, b)
-            self.ends.insert(i, e)
-        # merge neighbours (intervals are disjoint by construction; merging
-        # only coalesces touching intervals to keep lists small)
-        i = 0
-        while i + 1 < len(self.starts):
-            if self.ends[i] >= self.starts[i + 1]:
-                self.ends[i] = max(self.ends[i], self.ends[i + 1])
-                del self.starts[i + 1]
-                del self.ends[i + 1]
-            else:
-                i += 1
+            # The set stays coalesced (ends[i] < starts[i + 1]), so the
+            # piece can only reach the left neighbour and a run of
+            # intervals that start within [b, e].
+            lo = bisect.bisect_left(starts, b)
+            if lo and ends[lo - 1] >= b:
+                lo -= 1
+            hi = bisect.bisect_right(starts, e, lo)
+            if hi == lo:
+                starts.insert(lo, b)
+                ends.insert(lo, e)
+                continue
+            starts[lo:hi] = [min(starts[lo], b)]
+            ends[lo:hi] = [max(ends[hi - 1], e)]
 
     def remove(self, pieces: Sequence[Tuple[int, int]]) -> None:
         """Exact inverse of add for backtracking search (pieces must be

@@ -303,6 +303,61 @@ def test_sim_backends_record_compile_execute_spans(obs_env):
     assert {n for n in rows if n.startswith("sim.")} == {"sim.execute", "sim.events"}
 
 
+# ========================================================== engine counters
+def _sobel_decode_counting_successes(monkeypatch):
+    """One CAPS-HMS decode of the pipelined Sobel; returns it with the
+    number of actors and of the period probes that found a schedule."""
+    import importlib
+
+    from repro.core.binding import CHANNEL_DECISIONS
+
+    # The module, not the function that repro.core re-exports under its name.
+    caps_mod = importlib.import_module("repro.core.caps_hms")
+
+    successes = []
+    probe = caps_mod.caps_hms
+
+    def counted(*args, **kwargs):
+        times = probe(*args, **kwargs)
+        successes.append(times is not None)
+        return times
+
+    monkeypatch.setattr(caps_mod, "caps_hms", counted)
+    gt, arch = make_pipelined_sobel()
+    rng = random.Random(0)
+    cores = sorted(arch.cores)
+    ba = {
+        a: rng.choice([p for p in cores if gt.actors[a].can_run_on(arch.cores[p].ctype)])
+        for a in gt.actors
+    }
+    cd = {c: rng.choice(CHANNEL_DECISIONS) for c in gt.channels}
+    res = caps_mod.decode_via_heuristic(gt, arch, cd, ba)
+    return res, len(gt.actors), sum(successes)
+
+
+def test_caps_hms_counts_attempts_and_candidates(obs_env, monkeypatch):
+    res, n_actors, n_ok = _sobel_decode_counting_successes(monkeypatch)
+    obs.flush()
+    counters = obs.summarize(obs_env)["counters"]
+    assert res.feasible and res.periods_tried >= 2 and n_ok >= 1
+    assert counters["caps_hms.attempts"] == res.periods_tried
+    assert counters["caps_hms.candidates"] >= n_actors * n_ok
+
+
+def test_caps_hms_counters_record_nothing_when_off(tmp_path, monkeypatch):
+    monkeypatch.delenv(obs.OBS_ENV, raising=False)
+    monkeypatch.setenv(obs.OBS_DIR_ENV, str(tmp_path / "never"))
+    obs.configure(None)
+    try:
+        assert not obs.enabled()
+        res, _, _ = _sobel_decode_counting_successes(monkeypatch)
+        obs.flush()
+        assert res.periods_tried >= 1
+        assert not (tmp_path / "never").exists()
+    finally:
+        obs.configure(None)
+
+
 # ========================================================= profiler bridge
 def _profile(fn):
     """Run ``fn`` under the profiler; the host events that are not ops, and

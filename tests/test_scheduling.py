@@ -1,5 +1,10 @@
-"""Scheduling: the paper's worked examples (Figs. 4, 5, 7) and validity
-invariants for both decoders on random graphs."""
+"""Scheduling: the paper's worked examples (Figs. 4, 5, 7), validity
+invariants for both decoders on random graphs, stored CAPS-HMS decodes of
+the paper's applications, and the utilization-set bookkeeping."""
+import bisect
+import hashlib
+import json
+import os
 import random
 
 import pytest
@@ -7,12 +12,14 @@ import pytest
 # is absent the proptest driver runs the same properties deterministically.
 from repro.scenarios.proptest import given, settings, st
 
+from repro.core import apps, paper_architecture
 from repro.core.architecture import ArchitectureGraph
 from repro.core.caps_hms import decode_via_heuristic
+from repro.core.dse import GenotypeSpace, evaluate_genotype, xi_mode
 from repro.core.graph import ApplicationGraph
 from repro.core.ilp import decode_via_ilp
 from repro.core.mrb import substitute_mrbs
-from repro.core.schedule import validate_schedule
+from repro.core.schedule import UtilizationSet, f_wrap, validate_schedule
 
 
 def fig1_graph() -> ApplicationGraph:
@@ -162,3 +169,95 @@ def test_capacity_enlargement_accommodates_schedule():
     assert res.feasible
     for c, gamma in res.schedule.capacities.items():
         assert gamma >= g.channels[c].capacity or gamma >= 1
+
+
+# ------------------------------------------------------- stored decodes
+DIGESTS = os.path.join(os.path.dirname(__file__), "data", "caps_hms_digests.json")
+# case -> (graph, ξ strategy, §VI pipeline delays); the unpipelined case
+# keeps zero-delay channels, so actors become ready one by one.
+STORED_CASES = {
+    "multicamera.Reference": (lambda: apps.multicamera(pipelined=True), "Reference", True),
+    "multicamera.MRB_Explore": (lambda: apps.multicamera(pipelined=True), "MRB_Explore", True),
+    "sobel.MRB_Explore": (apps.sobel, "MRB_Explore", True),
+    "multicamera.MRB_Explore.unpipelined": (apps.multicamera, "MRB_Explore", False),
+}
+
+
+def _sha(obj) -> str:
+    return hashlib.sha256(json.dumps(obj, sort_keys=True).encode()).hexdigest()
+
+
+@pytest.mark.parametrize("case", sorted(STORED_CASES))
+def test_caps_hms_reproduces_stored_decodes(case):
+    """Twelve seeded genotypes per case decode to the stored schedules
+    (period, every task start, bindings, capacities, periods_tried) and
+    objective vectors, digested as SHA-256 of their sorted-key JSON."""
+    with open(DIGESTS) as f:
+        stored = json.load(f)[case]
+    make_graph, strategy, pipelined = STORED_CASES[case]
+    space = GenotypeSpace(make_graph(), paper_architecture())
+    rng = random.Random(17)
+    for i, want in enumerate(stored):
+        genotype = space.random(rng, xi_mode(strategy))
+        results = []
+
+        def decoder(g, arch, decisions, binding, time_budget_s=None):
+            results.append(decode_via_heuristic(g, arch, decisions, binding))
+            return results[-1]
+
+        ind = evaluate_genotype(space, genotype, decoder=decoder, pipelined=pipelined)
+        assert _sha(results[0].to_json()) == want["decode"], (case, i)
+        assert _sha(list(ind.objectives)) == want["objectives"], (case, i)
+    assert len(stored) == 12
+
+
+# ----------------------------------------------------- utilization sets
+def _full_pass_add(starts, ends, pieces):
+    """Reference add: insert every piece, then coalesce in one full pass."""
+    for b, e in pieces:
+        if b >= e:
+            continue
+        i = bisect.bisect_left(starts, b)
+        starts.insert(i, b)
+        ends.insert(i, e)
+    i = 0
+    while i + 1 < len(starts):
+        if ends[i] >= starts[i + 1]:
+            ends[i] = max(ends[i], ends[i + 1])
+            del starts[i + 1]
+            del ends[i + 1]
+        else:
+            i += 1
+
+
+@settings(max_examples=60, deadline=None)
+@given(seed=st.integers(0, 1_000_000), period=st.integers(1, 40))
+def test_utilization_set_matches_full_pass_merge(seed, period):
+    """Random add/remove/query sequences over wrapped, touching and
+    overlapping pieces (validate_schedule adds overlaps on purpose): the
+    neighbour-only merge leaves the same intervals as a full pass, and
+    busy_end agrees with conflict(f_wrap(...))."""
+    rng = random.Random(seed)
+    u, ref = UtilizationSet(), UtilizationSet()
+    for _ in range(80):
+        op = rng.random()
+        if op < 0.45:
+            pieces = f_wrap(period, rng.randint(-period, 3 * period), rng.randint(0, period + 2))
+            if rng.random() < 0.3:  # an arbitrary sequence, possibly empty
+                pieces = [
+                    (b, b + rng.randint(-1, 6)) for b in rng.sample(range(period), min(period, 3))
+                ]
+            u.add(pieces)
+            _full_pass_add(ref.starts, ref.ends, pieces)
+            assert (u.starts, u.ends) == (ref.starts, ref.ends)
+        elif op < 0.6 and u.starts:
+            i = rng.randrange(len(u.starts))
+            b = rng.randint(u.starts[i], u.ends[i] - 1)
+            e = rng.randint(b + 1, u.ends[i])
+            u.remove([(b, e)])
+            ref.remove([(b, e)])
+            assert (u.starts, u.ends) == (ref.starts, ref.ends)
+        else:
+            start, dur = rng.randint(-2 * period, 4 * period), rng.randint(-1, period + 2)
+            hit = u.conflict(f_wrap(period, start, dur))
+            assert u.busy_end(period, start, dur) == (-1 if hit is None else hit[1])
